@@ -47,6 +47,7 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import itertools
+import traceback
 from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.core.estimator import CostModel
@@ -568,9 +569,11 @@ class Cluster:
     def _on_exec_error(self, inst: Instance, now: float, exc: Exception):
         """An executor step raised (injected or real device failure):
         quarantine the instance — its pipeline state is suspect — and
-        evacuate.  The watchdog's probation re-admits it later."""
+        evacuate.  The watchdog's probation re-admits it later.  The
+        traceback is kept, so a launcher that refuses to exit 0 after a
+        device error can say where it came from."""
         self.exec_errors += 1
-        self.last_exec_error = repr(exc)
+        self.last_exec_error = "".join(traceback.format_exception(exc))
         self.quarantine_instance(inst, now, reason="exec_error")
 
     # ---- request abort (client disconnect) ----------------------------

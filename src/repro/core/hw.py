@@ -29,6 +29,22 @@ class HardwareSpec:
 
 V5E = HardwareSpec()
 
+#: the peaks this repo assumes, keyed by ``jax.Device.device_kind``
+#: (Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+#: at 819 GB/s).  JAX reports a v5e as "TPU v5 lite".
+PEAKS_BY_KIND = {"TPU v5 lite": V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """Peaks for a device kind; a kind not in the table is an error,
+    never a silent v5e default."""
+    try:
+        return PEAKS_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks recorded for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS_BY_KIND)}") from None
+
 
 @dataclasses.dataclass(frozen=True)
 class InstanceSpec:

@@ -462,13 +462,7 @@ class JaxExecutor:
         climbing value under a steady workload is a recompile storm —
         usually a shape-bucketing bug — and shows up here long before
         it shows up in latency percentiles."""
-        n = 0
-        for fn in self._jitted:
-            try:
-                n += fn._cache_size()
-            except Exception:      # private API: absent on some versions
-                return -1
-        return n
+        return sum(fn._cache_size() for fn in self._jitted)
 
     @property
     def horizon_capable(self) -> bool:

@@ -22,11 +22,11 @@ _ENV = "REPRO_KERNELS_INTERPRET"
 
 
 def backend_is_tpu() -> bool:
+    """True when JAX's default backend is a TPU.  A backend that fails
+    to initialize raises here: falling back to the interpreter would
+    hide a missing device behind a slow stand-in."""
     import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:          # backend init failure: interpret is safe
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:

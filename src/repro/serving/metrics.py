@@ -367,8 +367,8 @@ class TelemetryWindow:
                     "horizon_calls": ex.horizon_calls,
                     "horizon_tokens": ex.horizon_tokens}
             jc = getattr(ex, "jit_compiles", None)
-            if jc is not None and (n := jc()) >= 0:
-                ex_g["jit_compiles"] = n
+            if jc is not None:
+                ex_g["jit_compiles"] = jc()
             gauges["exec"] = ex_g
         hist = getattr(inst, "horizon_hist", None)
         if hist:

@@ -26,7 +26,8 @@ class ServingConfig:
         default_factory=lambda: Sliders(n_p=2, n_d=2, s_p=1024, s_d=512))
     hbm_blocks: int = 8192            # KV blocks per instance
     block_size: int = 16
-    max_ctx: int = 16384
+    max_ctx: int = 16384              # also the real engine's max_seq
+    n_slots: int = 64                 # real engine: request rows per instance
     prefix_cache: bool = False        # shared-prefix KV cache per instance
     spill_blocks: int = 0             # host-RAM spill tier per instance
 

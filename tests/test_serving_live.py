@@ -85,11 +85,14 @@ def test_live_streamed_run_survives_role_flip(setup):
 
 @pytest.mark.slow
 def test_live_cli_smoke(setup, capsys, monkeypatch):
+    # tests keep the persistent compile cache off
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
     monkeypatch.setattr("sys.argv", [
-        "serve", "--engine", "live", "--arch", "smollm-135m",
+        "serve", "--engine", "live", "--reduced", "--arch", "smollm-135m",
         "--qps", "4", "--n", "6", "--controller",
         "--ttft-slo", "5.0", "--tpot-slo", "0.5"])
     serve.main()
     out = capsys.readouterr().out
+    assert '"engine": "smollm-135m-smoke"' in out
     assert '"streamed_tokens"' in out
     assert '"real_tokens"' in out
