@@ -4,9 +4,10 @@ through the HTTP server, and check what comes back.
     python chip_smoke.py
 
 Builds the real-engine cluster the way ``python -m repro.launch.serve
---serve`` does (``build_engine`` + ``make_server``): 30 layers, bf16,
-random weights from a seed, the TaiChi policy with 2 P-heavy and 2
-D-heavy instances on the one chip, paged Pallas kernels lowered
+--serve`` does with its defaults (``build_engine`` + ``make_server``):
+30 layers, bf16, random weights from a seed, the TaiChi policy with 2
+P-heavy and 2 D-heavy instances (chunks of 1,024 and 256 tokens, an
+8-step decode horizon) on the one chip, paged Pallas kernels lowered
 natively.  Then it
 
   * sends 8 concurrent ``/v1/completions`` requests (half of them
@@ -14,8 +15,9 @@ natively.  Then it
     every answer, that no executor step failed, and that at least one
     request's KV migrated from a P-heavy to a D-heavy instance;
   * checks that the compiled served steps (the mixed prefill+decode
-    step and the fused decode horizon) hold the Pallas kernels
-    (``tpu_custom_call``) and fit the device next to the KV pools;
+    step and the fused decode horizon), at the largest shapes the
+    server can ask for, hold the Pallas kernels (``tpu_custom_call``)
+    and fit the device next to the KV pools;
   * compares the logits of one 300-token prompt (two prefill chunks,
     then 8 decode steps) through the paged kernels and through the jnp
     gather read, with the same weights in bf16.
@@ -75,9 +77,9 @@ def fact(**kw):
 # client side
 # ---------------------------------------------------------------------------
 
-def make_prompts(seed: int = 0):
+def make_prompts():
     """ASCII prompts (one byte = one token) of PROMPT_LENS tokens."""
-    rng = random.Random(seed)
+    rng = random.Random(0)
     words = ["taichi", "prefill", "decode", "slider", "chunk", "tensor",
              "latency", "goodput", "block", "cache", "request", "token"]
     out = []
@@ -170,13 +172,16 @@ def serve_requests(engine, slo):
 
 def served_steps_compiled(engine, horizon: int):
     """Compile (or load from the cache) the two served step programs at
-    the largest shapes the smoke run uses, over 8 rows and a 1,024-token
-    table: the mixed prefill+decode step with a full P-heavy chunk, and
-    the fused ``horizon``-step decode."""
+    the largest shapes the server can ask for: every slot, a full block
+    table, and for the mixed prefill+decode step a full P-heavy chunk;
+    the decode horizon runs ``horizon`` fused steps."""
     import jax
     import jax.numpy as jnp
+    from repro.engine.batching import bucket, bucket_batch
     ex = engine.cluster.instances[0].executor
-    B, T, NB = 8, engine.sc.sliders.s_p, 1024 // engine.sc.block_size
+    B, NB = bucket_batch(ex.n_slots), ex.kv.max_blocks
+    T = bucket(engine.sc.sliders.s_p, ex.t_buckets)
+    fact(served_step_shapes={"B": B, "T": T, "NB": NB, "K": horizon})
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     key = jax.random.PRNGKey(0)
     mixed = ex._mixed_fused.lower(
@@ -188,7 +193,7 @@ def served_steps_compiled(engine, horizon: int):
     return {"mixed": mixed, "horizon": fused}
 
 
-def compare_logits(cfg, params, *, seed: int = 1):
+def compare_logits(cfg, params):
     """Largest |kernel - jnp| logit difference over a two-chunk prefill
     and COMPARE_DECODE decode steps through the paged pool, relative to
     the largest |jnp| logit.  Returns (relative diff, absolute diff,
@@ -202,7 +207,7 @@ def compare_logits(cfg, params, *, seed: int = 1):
     n_tok = 2 * COMPARE_CHUNK + COMPARE_DECODE
     n_blk = -(-n_tok // bs)
     tables = jnp.arange(n_blk, dtype=jnp.int32)[None]
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(1)
     ids = rng.integers(0, cfg.vocab_size, n_tok).astype(np.int32)
 
     def run(kernels: bool):
@@ -239,19 +244,18 @@ def compare_logits(cfg, params, *, seed: int = 1):
 def main():
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent / "src"))
     from repro.launch import serve
-    serve.enable_compile_cache()
+    cache = serve.enable_compile_cache()
     import jax
     from jax import monitoring
 
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
-    fact(device=device)
+    fact(device=device, compile_cache=cache)
     if dev.platform != "tpu":
         raise CheckFailed(f"no TPU: JAX's first device is {dev.platform}")
     from repro.core.hw import hardware_for
     from repro.core.latency import SLO
-    from repro.core.policies import Sliders
     from repro.kernels import resolve_interpret
     hw = hardware_for(dev.device_kind)
     fact(assumed_peaks={"name": hw.name, "peak_flops": hw.peak_flops,
@@ -275,9 +279,7 @@ def main():
 
     slo = SLO(ttft=5.0, tpot=0.5)        # loose: this run is about tokens
     t0 = time.perf_counter()
-    engine = serve.build_engine(
-        ARCH, slo, policy="taichi",
-        sliders=Sliders(n_p=2, n_d=2, s_p=512, s_d=128), horizon=HORIZON)
+    engine = serve.build_engine(ARCH, slo, policy="taichi", horizon=HORIZON)
     stats = dev.memory_stats()
     fact(engine=engine.cfg.name, layers=engine.cfg.num_layers,
          d_model=engine.cfg.d_model, dtype=engine.cfg.dtype,

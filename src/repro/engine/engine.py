@@ -456,6 +456,18 @@ class JaxExecutor:
                         _prefill_packed, _prefill_slot, _mixed_fused,
                         _sample_batch, _horizon_paged, _horizon_dense]
 
+    @staticmethod
+    def transient_pools(horizon: int) -> int:
+        """Pool-sized temporaries the largest paged program holds beside
+        the pool it updates, for sizing the pool: one for a migration's
+        block scatter (a new pool is written before the old one is
+        freed), which also covers a mixed step; three once the fused
+        decode horizon runs (``horizon`` > 1), as XLA copies the pool
+        ``_horizon_paged`` carries from one scanned step to the next
+        (2.7 pools in ``compiled.memory_analysis()`` for a v5e, at any
+        K >= 2; tests/test_tpu_compile.py holds both bounds)."""
+        return 3 if horizon > 1 else 1
+
     def jit_compiles(self) -> int:
         """Total traced-and-compiled variants across this executor's
         jitted entry points (shape buckets x static args).  A steadily

@@ -77,9 +77,7 @@ def compile_cache_dir() -> str:
 def enable_compile_cache() -> str:
     """Turn the persistent compile cache on before the first compile.
     JAX reads ``$JAX_COMPILATION_CACHE_DIR`` itself; only without it is
-    a directory set here.  The four instances of a cluster trace the
-    same step programs, so within one run the cache also spares the
-    repeats."""
+    a directory set here."""
     path = compile_cache_dir()
     if not os.environ.get(_CACHE_ENV):
         jax.config.update("jax_compilation_cache_dir", path)
@@ -96,20 +94,13 @@ def model_config(arch: str, reduced: bool = False) -> ModelConfig:
     return reduced_config(arch) if reduced else get_config(arch)
 
 
-#: pool-sized temporaries of the fused K-step decode horizon: XLA copies
-#: the pool it carries from one fused step to the next (2.8 pools in
-#: ``compiled.memory_analysis()`` for a v5e, at any K >= 2)
-HORIZON_TRANSIENT_POOLS = 3
-
-
 def kv_pool_blocks(cfg: ModelConfig, n_instances: int, block_size: int,
                    device=None, transient_pools: int = 1) -> int:
     """KV blocks per instance that fit the device next to what it
-    already holds (the shared weights): ``n_instances`` pools, the
-    largest pool-sized transient one program makes — a migration's block
-    scatter writes a new pool before the old one is freed (1), the
-    decode horizon copies its carried pool (``HORIZON_TRANSIENT_POOLS``)
-    — and the step-temporary reserve."""
+    already holds (the shared weights): ``n_instances`` pools,
+    ``transient_pools`` more for the largest pool-sized temporaries one
+    program makes (``JaxExecutor.transient_pools``), and the
+    step-temporary reserve."""
     from repro.engine.paged import PagedKVCache
     device = device or jax.devices()[0]
     stats = device.memory_stats()
@@ -145,8 +136,7 @@ def build_engine(arch: str, slo: SLO, *, reduced: bool = False,
                  n_slots: Optional[int] = None,
                  max_seq: Optional[int] = None,
                  hbm_blocks: Optional[int] = None,
-                 async_exec: bool = True, horizon: int = 1,
-                 seed: int = 0) -> Engine:
+                 async_exec: bool = True, horizon: int = 1) -> Engine:
     """Build the JaxExecutor cluster every real-engine mode serves from.
 
     Sizes not given come from ``ServingConfig`` (``REDUCED_SIZES`` with
@@ -169,11 +159,11 @@ def build_engine(arch: str, slo: SLO, *, reduced: bool = False,
     given = dict(n_slots=n_slots, max_ctx=max_seq, hbm_blocks=hbm_blocks)
     sc = dataclasses.replace(
         sc, **{k: v for k, v in given.items() if v is not None})
-    params = tf.init_params(jax.random.PRNGKey(seed), cfg)
+    params = tf.init_params(jax.random.PRNGKey(0), cfg)
     if hbm_blocks is None and not reduced:
         sc.hbm_blocks = kv_pool_blocks(
             cfg, sliders.n_p + sliders.n_d, sc.block_size,
-            transient_pools=HORIZON_TRANSIENT_POOLS if horizon > 1 else 1)
+            transient_pools=JaxExecutor.transient_pools(horizon))
     factory = lambda: JaxExecutor(cfg, params, n_slots=sc.n_slots,
                                   max_seq=sc.max_ctx,
                                   hbm_blocks=sc.hbm_blocks,

@@ -89,10 +89,12 @@ def test_kv_pool_sized_from_device_memory():
     assert blocks == free // 5 // per_block
     assert 5 * blocks * per_block <= free < 5 * (blocks + 1) * per_block
     # the decode horizon's pool copies take the place of the one transient
+    from repro.engine.engine import JaxExecutor
+    assert JaxExecutor.transient_pools(1) == 1
     blocks = serve.kv_pool_blocks(
         cfg, 4, 16, device=dev,
-        transient_pools=serve.HORIZON_TRANSIENT_POOLS)
-    assert blocks == free // (4 + serve.HORIZON_TRANSIENT_POOLS) // per_block
+        transient_pools=JaxExecutor.transient_pools(8))
+    assert blocks == free // (4 + JaxExecutor.transient_pools(8)) // per_block
 
 
 def test_kv_pool_needs_a_memory_limit():

@@ -100,14 +100,20 @@ def test_paged_prefill_compiles(one_chip, name, hq, hkv, d, quant):
     _assert_kernel(compiled)
 
 
-@pytest.mark.parametrize("B,T", [(16, 1), (4, 256)],
-                         ids=["decode", "prefill"])
-def test_served_mixed_step_compiles(one_chip, monkeypatch, B, T):
-    """The executor's own fused mixed step for smollm-135m at published
-    widths (30 layers, bf16) over a 65,536-token pool, kernels on."""
+#: the largest step ``--serve`` asks for by default: every one of
+#: ServingConfig's 64 slots, a full 1,024-token P-heavy chunk, and the
+#: whole block table of a 16,384-token context
+SERVED_MAX = (64, 1024, 1024)
+
+
+def _served_executor(one_chip, monkeypatch):
+    """smollm-135m at published widths (30 layers, bf16), kernels on:
+    the executor, its parameters, and a 65,536-token pool, as shapes."""
+    from repro.engine import batching
     from repro.engine.engine import JaxExecutor
     from repro.models import attention
     from repro.models import transformer as tf
+    from repro.sim.simulator import ServingConfig
     monkeypatch.setenv("REPRO_KERNELS_INTERPRET", "0")
     monkeypatch.setattr(attention, "_USE_KERNELS", True)
     cfg = get_config("smollm-135m")
@@ -116,13 +122,47 @@ def test_served_mixed_step_compiles(one_chip, monkeypatch, B, T):
     params = on_chip(tf.abstract_params(cfg))
     pool = on_chip(jax.eval_shape(
         lambda: tf.init_paged_cache(cfg, NUM_BLOCKS, BLOCK)))
-    # a small real pool: only the jitted step is lowered, on shapes
-    ex = JaxExecutor(cfg, params, n_slots=16, max_seq=16384, hbm_blocks=4)
+    sc = ServingConfig(model=cfg.name)
+    # a small real pool: only the jitted steps are lowered, on shapes
+    ex = JaxExecutor(cfg, params, n_slots=sc.n_slots, max_seq=sc.max_ctx,
+                     hbm_blocks=4, cache_block_size=BLOCK)
+    assert (batching.bucket_batch(sc.n_slots),
+            batching.bucket(sc.sliders.s_p, ex.t_buckets),
+            ex.kv.max_blocks) == SERVED_MAX
+    return ex, params, pool
+
+
+def _pool_bytes(pool):
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+
+
+@pytest.mark.parametrize("B,T,nb", [(16, 1, NB), (4, 256, NB), SERVED_MAX],
+                         ids=["decode", "prefill", "served_max"])
+def test_served_mixed_step_compiles(one_chip, monkeypatch, B, T, nb):
+    """The executor's own fused mixed step, up to the served maximum."""
+    ex, params, pool = _served_executor(one_chip, monkeypatch)
     i32 = lambda *s: _sds(one_chip, s, jnp.int32)
     compiled = ex._mixed_fused.lower(
-        params, pool, i32(B, T), i32(B), i32(B), i32(B, NB),
+        params, pool, i32(B, T), i32(B), i32(B), i32(B, nb),
         _sds(one_chip, (2,), jnp.uint32)).compile()
     _assert_kernel(compiled)
-    # the step fits the chip beside its pool
-    mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 1 << 30
+    # the step fits the chip beside its pool, within what the pool
+    # sizing reserves for it
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1 << 30
+    assert temp <= ex.transient_pools(1) * _pool_bytes(pool)
+
+
+def test_served_horizon_fits_transient_pools(one_chip, monkeypatch):
+    """The fused 8-step decode horizon at the served maximum holds its
+    pool copies within the transients the pool sizing reserves."""
+    ex, params, pool = _served_executor(one_chip, monkeypatch)
+    B, _, nb = SERVED_MAX
+    i32 = lambda *s: _sds(one_chip, s, jnp.int32)
+    compiled = ex._horizon_paged.lower(
+        params, pool, i32(B), i32(B), i32(B), i32(B, nb),
+        _sds(one_chip, (2,), jnp.uint32), K=8).compile()
+    _assert_kernel(compiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 2 * _pool_bytes(pool) < temp                  # the copies exist
+    assert temp <= ex.transient_pools(8) * _pool_bytes(pool)
