@@ -28,11 +28,6 @@ front-end smoke bench (``benchmarks/out/frontend_bench.json``): the
 socket-level streamed tokens/s floor and the per-token wire-overhead
 ceiling.
 
-With ``--trace`` (or ``--trace-only``) it re-checks the lifecycle
-tracing overhead bench (``benchmarks/out/trace_overhead_bench.json``):
-traced runs must be bit-identical to untraced ones (goodput ratio at
-1.0) and tracing's wall-time cost must stay under its ceiling.
-
 Usage:  python benchmarks/check_regression.py [--fresh path] [--baseline path]
 """
 from __future__ import annotations
@@ -181,43 +176,6 @@ def check_frontend(path: str) -> int:
     return 0
 
 
-def check_trace(path: str) -> int:
-    """Gate over benchmarks/out/trace_overhead_bench.json: tracing must
-    be strictly observational (bit-identical request outcomes, goodput
-    ratio at 1.0 within the recorded floor) and its wall-time cost must
-    stay under the recorded ceiling — a structural leak of the tracer
-    onto the hot path, not runner jitter, is what trips this."""
-    with open(path) as f:
-        res = json.load(f)
-    b = res["bounds"]
-    failures = []
-    ident = res["bit_identical"]
-    status = "ok" if ident else "REGRESSION"
-    print(f"{'bit_identical':>26}: {ident} (must be True) {status}")
-    if not ident:
-        failures.append("traced run produced different request outcomes "
-                        "— the tracer is no longer observational")
-    ratio = res["goodput_ratio"]
-    status = "ok" if ratio >= b["goodput_ratio_floor"] else "REGRESSION"
-    print(f"{'goodput_ratio':>26}: {ratio:.4f} "
-          f"(floor {b['goodput_ratio_floor']}) {status}")
-    if ratio < b["goodput_ratio_floor"]:
-        failures.append(f"traced goodput ratio {ratio:.4f} < floor "
-                        f"{b['goodput_ratio_floor']}")
-    over = res["wall_overhead_frac"]
-    status = "ok" if over <= b["wall_overhead_ceil"] else "REGRESSION"
-    print(f"{'wall_overhead_frac':>26}: {over:.3f} "
-          f"(ceiling {b['wall_overhead_ceil']}) {status}")
-    if over > b["wall_overhead_ceil"]:
-        failures.append(f"tracing wall overhead {over:.3f} > ceiling "
-                        f"{b['wall_overhead_ceil']}")
-    if failures:
-        print("\nFAIL:\n  " + "\n  ".join(failures))
-        return 1
-    print("\nOK: tracing stays observational and cheap")
-    return 0
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--fresh",
@@ -243,15 +201,9 @@ def main():
         help="also gate the fault-injection chaos bench JSON")
     ap.add_argument("--chaos-only", action="store_true",
                     help="gate only the chaos bench JSON")
-    ap.add_argument("--trace", nargs="?", const=os.path.join(
-        HERE, "out", "trace_overhead_bench.json"),
-        help="also gate the lifecycle-tracing overhead bench JSON")
-    ap.add_argument("--trace-only", action="store_true",
-                    help="gate only the tracing overhead JSON")
     args = ap.parse_args()
     rc = 0
-    if not (args.kv_only or args.frontend_only or args.chaos_only
-            or args.trace_only):
+    if not (args.kv_only or args.frontend_only or args.chaos_only):
         rc |= check(args.fresh, args.baseline, args.tol)
     if args.kv or args.kv_only:
         rc |= check_kv_pressure(args.kv or os.path.join(
@@ -262,9 +214,6 @@ def main():
     if args.chaos or args.chaos_only:
         rc |= check_chaos(args.chaos or os.path.join(
             HERE, "out", "chaos_bench.json"))
-    if args.trace or args.trace_only:
-        rc |= check_trace(args.trace or os.path.join(
-            HERE, "out", "trace_overhead_bench.json"))
     sys.exit(rc)
 
 

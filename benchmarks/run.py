@@ -24,7 +24,6 @@ MODULES = [
     "kv_pressure_bench",     # multi-tier KV under a constrained pool
     "chaos_bench",           # goodput under injected faults vs fail-stop
     "frontend_bench",        # HTTP/SSE front-end socket-level smoke
-    "trace_overhead_bench",  # lifecycle tracing cost + bit-identicality
     "kernel_bench",          # kernels microbench
     "roofline_report",       # dry-run roofline table
 ]

@@ -275,7 +275,7 @@ class Cluster:
         inst = self.policy.on_arrival(req, now)
         if inst is not None:
             if self.tracer is not None:
-                self.tracer.event(req.rid, now, "route", iid=inst.iid)
+                self.tracer.route(req.rid, now, iid=inst.iid)
             self._schedule_iter(inst, now)
             return
         recovered = req.n_recoveries > 0 or req.first_token_time is not None
@@ -290,8 +290,7 @@ class Cluster:
                        key=lambda i: i.queued_prefill_tokens())
             inst.enqueue_prefill(req)
             if self.tracer is not None:
-                self.tracer.event(req.rid, now, "route", iid=inst.iid,
-                                  forced=True)
+                self.tracer.route(req.rid, now, iid=inst.iid, forced=True)
             self._schedule_iter(inst, now)
             return
         if not capacity:
@@ -725,8 +724,8 @@ class Cluster:
                 self._schedule_iter(inst, now + 0.01)
             else:
                 self._handle(now, ITER, inst.iid)
-        for req, t in res.token_events:
-            inst.token_sink(req, t)
+        for req, t, tok in res.token_events:
+            inst.token_sink(req, t, tok)
         if self.recovery is not None:
             for req in res.finished:
                 self.recovery.drop(req.rid)

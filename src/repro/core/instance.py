@@ -17,6 +17,8 @@ from repro.cache.prefix_cache import PrefixCache
 from repro.core.estimator import CostModel
 from repro.engine.kvcache import BlockAllocator
 from repro.engine.request import Request, State
+from repro.serving.tracing import (NO_SPAN, STEP_COMMIT, STEP_DISPATCH,
+                                   STEP_PLAN, STEP_SYNC)
 
 P_HEAVY = "P"
 D_HEAVY = "D"
@@ -62,13 +64,13 @@ class IterationPlan:
 class CommitResult:
     """Outcome of one committed iteration (second half of the
     dispatch/commit split).  ``token_events`` carries the deferred
-    per-token sink calls ``(request, time)`` when the caller asked for
-    deferred emission — so it can dispatch the next horizon before the
-    host spends time streaming these."""
+    per-token sink calls ``(request, time, token id or None)`` when the
+    caller asked for deferred emission — so it can dispatch the next
+    horizon before the host spends time streaming these."""
     duration: float
     prefill_done: List[Request]
     finished: List[Request]
-    token_events: List[Tuple[Request, float]]
+    token_events: List[Tuple[Request, float, Optional[int]]]
 
 
 class Executor(Protocol):
@@ -162,7 +164,8 @@ class Instance:
         # online-serving hooks: a per-token callback installed by the
         # serving loop (streaming), and drain-and-flip reconfiguration
         # state driven by the adaptive slider controller
-        self.token_sink: Optional[Callable[[Request, float], None]] = None
+        self.token_sink: Optional[
+            Callable[[Request, float, Optional[int]], None]] = None
         self.draining: bool = False
         self.pending_flip: Optional[Tuple[str, int]] = None
         self.role_flips: int = 0
@@ -181,8 +184,13 @@ class Instance:
         self.overrun: float = 0.0
         self.fail_count: int = 0
         self.quarantine_count: int = 0
-        #: request-lifecycle tracer (wired by ServingLoop; None = off)
+        #: request-lifecycle tracer (wired by ServingLoop; None = off),
+        #: with the traced step count and the in-flight step's seq and
+        #: dispatch stamp on the tracer's clock
         self.tracer = None
+        self._step_seq = 0
+        self._inflight_seq: Optional[int] = None
+        self._inflight_wall: Optional[float] = None
         # accounting
         self.busy_until: float = 0.0
         self.iterations: int = 0
@@ -562,7 +570,10 @@ class Instance:
         device results (``step_async`` when the executor has one).
         Returns the modeled duration, or None when nothing is
         schedulable; ``commit_iteration`` finishes the iteration."""
-        plan = self.build_plan(now)
+        tr = self.tracer
+        with (NO_SPAN if tr is None
+              else tr.step(STEP_PLAN, iid=self.iid, seq=self._step_seq)):
+            plan = self.build_plan(now)
         if plan.empty():
             return None
         dur = self.iteration_duration(plan)
@@ -586,10 +597,40 @@ class Instance:
         # every request riding the plan (a fully-taken prefill is
         # already popped off the queue by build_plan)
         self._inflight = (plan, None, now, dur)
-        pending = step_fn(plan) if step_fn is not None else None
+        if tr is None:
+            pending = step_fn(plan) if step_fn is not None else None
+        else:
+            pending = self._traced_dispatch(tr, step_fn, plan, now)
         self._inflight = (plan, pending, now, dur)
         self.busy_until = now + dur
         return dur
+
+    def _traced_dispatch(self, tr, step_fn, plan: IterationPlan,
+                         now: float):
+        """``step_fn(plan)`` inside a ``taichi.step.dispatch`` span; the
+        dispatch's stamp opens the prefill phase of its chunks."""
+        seq = self._inflight_seq = self._step_seq
+        self._step_seq += 1
+        self._inflight_wall = tr.on_dispatch(now)
+        K = plan.horizon
+        if K > 1:
+            kind = "horizon"
+        elif not plan.prefill_items:
+            kind = "decode"
+        else:
+            kind = "mixed" if plan.decode_reqs else "prefill"
+        compiles = getattr(self.executor, "jit_compiles", None)
+        n0 = compiles() if compiles is not None else 0
+        with tr.step(STEP_DISPATCH, iid=self.iid, seq=seq, kind=kind, K=K,
+                     prefill_tokens=plan.prefill_tokens,
+                     decode_rows=len(plan.decode_reqs)) as sp:
+            pending = step_fn(plan) if step_fn is not None else None
+        # known only after the call: kept in memory, not in the profile
+        sp.attrs["padded_t"] = getattr(pending, "padded_t", None)
+        n1 = compiles() if compiles is not None else 0
+        if n1 > n0:
+            sp.attrs["compiled"] = n1 - n0
+        return pending
 
     def has_inflight(self) -> bool:
         return self._inflight is not None
@@ -611,11 +652,22 @@ class Instance:
         per-token sink callbacks are returned instead of fired, so the
         caller can dispatch the next horizon first and stream these
         while the device computes (one-horizon-lagged consumption)."""
+        tr = self.tracer
+        if tr is None:
+            return self._commit_iteration(defer_emit, None)
+        with tr.step(STEP_COMMIT, iid=self.iid, seq=self._inflight_seq):
+            return self._commit_iteration(defer_emit, tr)
+
+    def _commit_iteration(self, defer_emit: bool, tr) -> CommitResult:
         plan, pending, t0, dur = self._inflight
+        seq, wall = self._inflight_seq, self._inflight_wall
         # resolve BEFORE discarding the in-flight record: if the
         # readback raises (device fault), the fault handler's
         # evacuation still sees the plan's requests
         if pending is not None:
+            if tr is not None and not pending.ready():
+                with tr.step(STEP_SYNC, iid=self.iid, seq=seq):
+                    pending.prefetch()
             eos = pending.resolve()
             emitted = pending.emitted
         else:
@@ -623,25 +675,29 @@ class Instance:
             emitted = {}
         self._inflight = None
         end = t0 + dur
-        events: List[Tuple[Request, float]] = []
+        events: List[Tuple[Request, float, Optional[int]]] = []
 
         def emit(req, t):
+            """Stream the token just recorded (its id where the executor
+            produced one)."""
             if self.token_sink is None:
                 return
+            n, toks = req.output_len, req.output_tokens
+            tok = toks[n - 1] if len(toks) >= n else None
             if defer_emit:
-                events.append((req, t))
+                events.append((req, t, tok))
             else:
-                self.token_sink(req, t)
+                self.token_sink(req, t, tok)
 
         prefill_done: List[Request] = []
         finished: List[Request] = []
-        tr = self.tracer
         for req, take in plan.prefill_items:
             if tr is not None:
-                # phase opens at the chunk's dispatch time (same-phase
+                # phase opens at the chunk's dispatch (same-phase
                 # transitions merge, so later chunks keep the start)
-                tr.phase(req.rid, t0, "prefill", iid=self.iid)
-                tr.event(req.rid, t0, "prefill_chunk", take=take,
+                tr.phase(req.rid, t0, "prefill", at=wall, iid=self.iid)
+                tr.event(req.rid, t0, "prefill_chunk", at=wall,
+                         iid=self.iid, seq=seq, take=take,
                          pos=req.prefill_pos,
                          cached=req.cached_prefix_len)
             req.prefill_pos += take
@@ -692,8 +748,9 @@ class Instance:
                 # per-commit decode record: fused horizon K, tokens this
                 # commit actually produced, and the co-batched prefill
                 # tokens that slowed every step (interference)
-                tr.event(req.rid, last_t[i], "decode_commit", k=K,
-                         tokens=c, interference=plan.prefill_tokens)
+                tr.event(req.rid, last_t[i], "decode_commit", iid=self.iid,
+                         seq=seq, k=K, tokens=c,
+                         interference=plan.prefill_tokens)
         for i, req in enumerate(plan.decode_reqs):
             if eos.get(req.rid, False) or req.done():
                 req.state = State.FINISHED

@@ -86,7 +86,10 @@ class PendingStep:
 
     ``emitted`` maps rid -> tokens produced this step (populated at
     resolve; consumers fall back to the plan's per-row budgets when a
-    rid is absent)."""
+    rid is absent).  ``padded_t`` is the token width a packed mixed
+    step was padded to (None elsewhere)."""
+
+    padded_t: Optional[int] = None
 
     def __init__(self, executor, arrays, apply_fn, horizon: int = 1):
         self._ex = executor
@@ -851,7 +854,9 @@ class JaxExecutor:
                         eos[req.rid] = True
             return eos
 
-        return PendingStep(self, (toks_dev,), apply)
+        step = PendingStep(self, (toks_dev,), apply)
+        step.padded_t = packed.tokens.shape[1]
+        return step
 
     def _step_horizon_paged(self, plan, K: int) -> PendingStep:
         """K fused decode steps over the block pool: grow every row's

@@ -229,7 +229,8 @@ def _dump_trace(loop, args, slo: SLO):
         tr.dump_jsonl(args.trace_jsonl)
         print(f"trace jsonl -> {args.trace_jsonl}", flush=True)
     print(json.dumps(
-        {"slo_violation_report": tr.violation_report(slo)},
+        {"slo_violation_report": tr.violation_report(slo),
+         "wait_report": tr.wait_report()},
         indent=2, default=str))
 
 

@@ -38,8 +38,8 @@ from repro.frontend.admission import AdmissionConfig, AdmissionQueue
 from repro.serving.clock import VirtualClock, WallClock
 from repro.serving.faults import FaultInjector
 from repro.serving.metrics import MetricsLog, TelemetryWindow
-from repro.serving.tracing import (PH_ADMISSION, PH_QUEUE, TraceConfig,
-                                   Tracer)
+from repro.serving.tracing import (LOOP_PACE, NO_SPAN, PH_ADMISSION,
+                                   PH_ROUTING, TraceConfig, Tracer)
 
 _DONE_STATES = (State.FINISHED, State.REJECTED, State.CANCELLED,
                 State.FAILED)
@@ -151,6 +151,7 @@ class ServingLoop:
                  tracing: Optional[TraceConfig] = None):
         self.cluster = cluster
         self.slo = slo
+        self.tracer: Optional[Tracer] = None
         self.clock = clock or VirtualClock()
         self.telemetry = TelemetryWindow(slo, window=window)
         # rates divide by seconds OBSERVED: the loop's start time is the
@@ -192,11 +193,10 @@ class ServingLoop:
         # request-lifecycle tracing (off by default: every call site
         # guards on ``tracer is None``, so an untraced run takes the
         # exact pre-tracing path)
-        if tracing is None:
-            self.tracer: Optional[Tracer] = None
-        else:
+        if tracing is not None:
             self.tracer = (tracing if isinstance(tracing, Tracer)
                            else Tracer(tracing))
+            self.tracer.clock = self._wall
             cluster.tracer = self.tracer
             for inst in cluster.instances:
                 inst.tracer = self.tracer
@@ -210,6 +210,19 @@ class ServingLoop:
             cluster.attach_faults(faults)
         if controller is not None:
             controller.bind(self)
+
+    @property
+    def clock(self):
+        return self._clock
+
+    @clock.setter
+    def clock(self, clock):
+        """Swapping the clock rebinds the tracer's: it reads a
+        ``WallClock`` at each hook and keeps event time otherwise."""
+        self._clock = clock
+        self._wall = clock if isinstance(clock, WallClock) else None
+        if self.tracer is not None:
+            self.tracer.clock = self._wall
 
     # ------------------------------------------------------------------
     # ingestion
@@ -242,7 +255,7 @@ class ServingLoop:
         if self.tracer is not None:
             self.tracer.begin(req, req.arrival,
                               PH_ADMISSION if self.admission is not None
-                              else PH_QUEUE)
+                              else PH_ROUTING)
         if self.admission is not None:
             self._enqueue_admission(req, priority)
         else:
@@ -299,7 +312,7 @@ class ServingLoop:
             self.telemetry.on_queue_wait(
                 now, max(now - entry.enq_time, 0.0))
             if self.tracer is not None:
-                self.tracer.phase(entry.req.rid, now, PH_QUEUE,
+                self.tracer.phase(entry.req.rid, now, PH_ROUTING,
                                   cls=entry.cls)
             self.cluster.submit(entry.req,
                                 t=max(entry.req.arrival, now))
@@ -408,10 +421,11 @@ class ServingLoop:
     # ------------------------------------------------------------------
     # event plumbing
     # ------------------------------------------------------------------
-    def _token_sink(self, req: Request, t: float):
+    def _token_sink(self, req: Request, t: float, tok: Optional[int]):
         self.telemetry.on_token(req, t)
+        if self.tracer is not None:
+            self.tracer.first_token(req.rid, t)
         handle = self._handles.get(req.rid)
-        tok = req.output_tokens[-1] if req.output_tokens else None
         if handle is not None:
             handle._emit(t, tok)
         if self._global_on_token is not None:
@@ -666,14 +680,22 @@ class ServingLoop:
             self._prefetch_ready(pending)
             self.clock.sleep_until(t)
             return True
-        while True:
-            self._prefetch_ready(pending)
-            if self._ingress_pending():
-                return False
-            now = self.clock.now
-            if now >= t:
-                return True
-            self.clock.sleep_until(min(t, now + self.PACE_SLICE))
+        self._prefetch_ready(pending)
+        if self._ingress_pending():
+            return False
+        now = self.clock.now
+        if now >= t:
+            return True
+        with (NO_SPAN if self.tracer is None
+              else self.tracer.step(LOOP_PACE, event_t=t)):
+            while True:
+                self.clock.sleep_until(min(t, now + self.PACE_SLICE))
+                self._prefetch_ready(pending)
+                if self._ingress_pending():
+                    return False
+                now = self.clock.now
+                if now >= t:
+                    return True
 
     # ------------------------------------------------------------------
     # the loop
@@ -731,6 +753,10 @@ class ServingLoop:
             snap["faults"] = fc
         if getattr(self.cluster, "recovery", None) is not None:
             snap["recovery"] = self.cluster.recovery_counters()
+        if self._wall is not None:
+            # how far the event clock (estimator time) runs behind the
+            # wall: arrivals wait in the heap until it catches up
+            snap["event_clock_lag_s"] = self._wall.now - self.cluster.now
         return snap
 
     # ------------------------------------------------------------------

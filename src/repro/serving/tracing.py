@@ -11,7 +11,10 @@ phases (contiguous, non-overlapping by construction — each ``phase()``
 call closes the current span at the new span's start time):
 
 * ``admission``   — router-side admission-queue wait
-* ``queue``       — event-heap wait + placement + instance prefill queue
+* ``routing``     — receipt (or admission release) -> the cluster
+                    handles the ARRIVAL: the event-heap wait, which the
+                    ``route`` event ends (left out when it took no time)
+* ``queue``       — routing -> first prefill chunk dispatched
                     (re-entered after preemption / crash recovery)
 * ``prefill``     — first chunk dispatched -> prefill complete
 * ``transfer``    — KV/state migration on the wire (incl. retries)
@@ -21,16 +24,38 @@ call closes the current span at the new span's start time):
 events ride on the timeline without breaking it: per-chunk prefill
 commits (with cache-hit offset), per-commit decode horizons (with
 co-batched prefill interference), transfer retries, preemptions,
-recoveries, routing decisions.  Cluster-scoped happenings (stalls,
-quarantines, controller actuations) land in a global event log.
+recoveries, routing decisions.  Chunk and horizon events name the
+``(iid, seq)`` of the executor step that ran them.  Cluster-scoped
+happenings (stalls, quarantines, controller actuations) land in a
+global event log.
 
-The tracer is **clock-agnostic**: every hook passes the time it already
-has (virtual event time in sim, wall time under ``WallClock``), so the
-same instrumentation serves both.  It is **observational only** — no
-RNG, no scheduling influence — so a traced run produces bit-identical
-request outcomes to an untraced one, and with ``tracing=None`` every
-call site short-circuits on ``tracer is None`` (zero overhead, asserted
-by ``benchmarks/trace_overhead_bench.py``).
+Clocks.  Under a ``VirtualClock`` every stamp is the event time the
+hook passes.  Under a ``WallClock`` (live serving) the cluster's event
+clock is estimator time and runs behind the wall under load, so each
+hook stamps the tracer's clock (the ``WallClock``'s reading, read at
+the hook) and keeps the event time beside it as the ``event_t``
+attribute.  A request opens at its receipt, which is a wall reading
+there, and its first token is stamped where tokens leave the serving
+loop.
+
+Step spans time the serving thread's work on each executor step, on
+the tracer's clock (the process's monotonic clock under a
+``VirtualClock``): ``taichi.step.plan`` (``Instance.build_plan``),
+``taichi.step.dispatch`` (the executor's ``step_async``: packing and
+launch), ``taichi.step.sync`` (a readback that blocked) and
+``taichi.step.commit`` (``Instance.commit_iteration``), each with
+``iid`` and the instance's step ``seq``, and ``taichi.loop.pace``, the
+live loop's wait for its next event.  Each dispatch under a
+``WallClock`` samples the event-clock lag.  While a JAX profiler trace
+runs, each step span is also a ``TraceAnnotation`` there, and a
+``taichi.clock`` annotation carrying the tracer's reading (``t_ns``),
+written at most once a second, lets ``profile_ns`` place the tracer's
+stamps on the profiler's timeline.
+
+The tracer is **observational only** — no RNG, no scheduling
+influence — so a traced run produces bit-identical request outcomes to
+an untraced one (``test_tracing_off_is_bit_identical``), and with
+``tracing=None`` every call site short-circuits on ``tracer is None``.
 
 Attribution:
 
@@ -53,18 +78,33 @@ from __future__ import annotations
 import dataclasses
 import json
 import re
+import statistics
+import time
 from collections import deque
 from typing import Dict, Iterator, List, Optional
 
 PH_ADMISSION = "admission"
+PH_ROUTING = "routing"
 PH_QUEUE = "queue"
 PH_PREFILL = "prefill"
 PH_TRANSFER = "transfer"
 PH_DECODE_WAIT = "decode_wait"
 PH_DECODE = "decode"
 
-PHASES = (PH_ADMISSION, PH_QUEUE, PH_PREFILL, PH_TRANSFER,
+PHASES = (PH_ADMISSION, PH_ROUTING, PH_QUEUE, PH_PREFILL, PH_TRANSFER,
           PH_DECODE_WAIT, PH_DECODE)
+
+#: step spans, on the tracer's clock and (while a profile runs) in the
+#: JAX profiler's trace; one ``taichi.`` prefix keeps them apart from
+#: any other annotations in the same trace
+STEP_PLAN = "taichi.step.plan"
+STEP_DISPATCH = "taichi.step.dispatch"
+STEP_SYNC = "taichi.step.sync"
+STEP_COMMIT = "taichi.step.commit"
+LOOP_PACE = "taichi.loop.pace"
+STEP_SPANS = (STEP_PLAN, STEP_DISPATCH, STEP_SYNC, STEP_COMMIT, LOOP_PACE)
+#: the profiler annotation that carries the tracer's clock reading
+CLOCK_SYNC = "taichi.clock"
 
 
 @dataclasses.dataclass
@@ -127,11 +167,69 @@ class RequestTrace:
         return None if self.t_end is None else self.t_end - self.t_begin
 
 
+class _NoSpan:
+    """What a step-span site enters when tracing is off."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+NO_SPAN = _NoSpan()
+
+
+class StepSpan:
+    """One step span: entered around the work it times; ``attrs`` may
+    grow inside (the profiler's copy keeps the attributes given at
+    entry)."""
+    __slots__ = ("tracer", "name", "attrs", "t0", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+        self.tracer = tracer
+        self.name = name
+        self.attrs = attrs
+        self.t0 = 0.0
+        self._ann = None
+
+    def __enter__(self):
+        self._ann = _annotation(self.name, self.attrs)
+        self._ann.__enter__()
+        self.t0 = self.tracer.now()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self.tracer.now()
+        self._ann.__exit__(*exc)
+        self.tracer.steps.append((self.name, self.t0, t1, self.attrs))
+        return False
+
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str, attrs: dict):
+    """A ``jax.profiler.TraceAnnotation`` (JAX imported on first use)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **attrs)
+
+
 class Tracer:
-    """Low-overhead span recorder.  All mutators take the caller's
-    timestamp — the tracer never reads a clock, so sim and live runs
-    use it identically.  Single-writer by design: every hook runs on
-    the engine/event thread (exports may run anywhere after the run)."""
+    """Low-overhead span recorder, single-writer by design: every hook
+    runs on the engine/event thread (exports may run anywhere after the
+    run).  ``clock`` is None under a ``VirtualClock`` — stamps are the
+    event times the hooks pass — and the ``WallClock`` in live serving,
+    read at each hook (``ServingLoop`` keeps it bound)."""
+
+    #: step spans and event-clock lag samples kept (ring buffers)
+    STEPS_KEPT = 65536
+    LAG_KEPT = 65536
+    #: seconds between ``taichi.clock`` annotations
+    CLOCK_SYNC_EVERY = 1.0
 
     def __init__(self, cfg: Optional[TraceConfig] = None):
         self.cfg = cfg or TraceConfig()
@@ -141,11 +239,65 @@ class Tracer:
         self.global_events: deque = deque(
             maxlen=self.cfg.max_global_events)
         self.dropped_traces = 0           # evicted past max_requests
+        self.clock = None
+        #: (name, t0, t1, attrs) per step span, on ``now()``
+        self.steps: deque = deque(maxlen=self.STEPS_KEPT)
+        #: (tracer clock, event time) at each dispatch under a WallClock
+        self.lag: deque = deque(maxlen=self.LAG_KEPT)
+        self._next_sync = float("-inf")
+
+    # ------------------------------------------------------------------
+    # clocks
+    # ------------------------------------------------------------------
+    def now(self) -> float:
+        """The tracer's clock: the bound ``WallClock``, else the
+        process's monotonic clock (step spans in simulation)."""
+        return self.clock.now if self.clock is not None else time.monotonic()
+
+    def _stamp(self, t: float, attrs: dict, at: Optional[float] = None):
+        """The stamp for a hook given event time ``t``: ``t`` itself
+        under a VirtualClock, else ``at`` or the clock read now, with
+        ``t`` kept in ``attrs`` as ``event_t``."""
+        if self.clock is None:
+            return t
+        attrs["event_t"] = t
+        return self.now() if at is None else at
+
+    def step(self, name: str, **attrs) -> StepSpan:
+        """A step span to enter around the work it times."""
+        return StepSpan(self, name, attrs)
+
+    def on_dispatch(self, t: float) -> float:
+        """Stamp one step dispatch at event time ``t``: samples the
+        event-clock lag (under a WallClock) and writes the profiler's
+        clock-sync annotation when one is due.  Returns the stamp."""
+        wall = self.now()
+        if self.clock is not None:
+            self.lag.append((wall, t))
+        if wall >= self._next_sync:
+            self._next_sync = wall + self.CLOCK_SYNC_EVERY
+            self.clock_sync()
+        return wall
+
+    def clock_sync(self):
+        """Write a ``taichi.clock`` annotation into the profiler's trace
+        (a no-op when no profile runs), carrying this clock's reading
+        at its start in integer nanoseconds (``t_ns``)."""
+        t_ns = int(self.now() * 1e9)
+        with _annotation(CLOCK_SYNC, {"t_ns": t_ns}):
+            pass
+
+    @staticmethod
+    def profile_ns(t: float, sync_start_ns: float, sync_t_ns: int) -> float:
+        """A tracer stamp ``t`` (seconds) on a profiler trace's timeline,
+        given one ``taichi.clock`` event of that trace: its start and
+        its ``t_ns``."""
+        return sync_start_ns + (t * 1e9 - sync_t_ns)
 
     # ------------------------------------------------------------------
     # recording
     # ------------------------------------------------------------------
-    def begin(self, req, t: float, phase: str = PH_QUEUE):
+    def begin(self, req, t: float, phase: str = PH_ROUTING):
         """Open a request's trace at its (receipt-stamped) arrival."""
         rid = req.rid
         if rid in self._live or rid in self._done:
@@ -155,21 +307,31 @@ class Tracer:
         tr.spans.append(Span(phase, t))
         self._live[rid] = tr
 
-    def phase(self, rid: int, t: float, name: str, **attrs):
-        """Transition to ``name``: closes the current span at ``t`` and
-        opens the new one there — contiguity by construction.  A
-        same-phase transition is a no-op (the original start stands)."""
+    def phase(self, rid: int, t: float, name: str, *,
+              at: Optional[float] = None, **attrs):
+        """Transition to ``name``: closes the current span at the stamp
+        and opens the new one there — contiguity by construction.  A
+        same-phase transition is a no-op (the original start stands),
+        and a ``routing`` span that took no time becomes the new phase.
+        ``at``: the wall stamp, for a hook that runs after the moment
+        it marks (ignored under a VirtualClock)."""
         tr = self._live.get(rid)
         if tr is None:
             return
         cur = tr.spans[-1]
         if cur.phase == name:
             return
-        t = max(t, cur.t0)          # never a negative-duration span
+        t = max(self._stamp(t, attrs, at), cur.t0)   # never negative
+        if cur.phase == PH_ROUTING and t == cur.t0:
+            cur.phase = name
+            if attrs:
+                cur.attrs = {**(cur.attrs or {}), **attrs}
+            return
         cur.t1 = t
         tr.spans.append(Span(name, t, attrs or None))
 
-    def event(self, rid: int, t: float, name: str, **attrs):
+    def event(self, rid: int, t: float, name: str, *,
+              at: Optional[float] = None, **attrs):
         if not self.cfg.events:
             return
         tr = self._live.get(rid)
@@ -178,11 +340,28 @@ class Tracer:
         if len(tr.events) >= self.cfg.max_events_per_request:
             tr.events_dropped += 1
             return
+        t = self._stamp(t, attrs, at)
         tr.events.append((t, name, attrs or None))
+
+    def route(self, rid: int, t: float, **attrs):
+        """The cluster routed a request: its ``route`` event, which ends
+        the ``routing`` phase (one stamp for both)."""
+        at = self.now() if self.clock is not None else None
+        self.event(rid, t, "route", at=at, **attrs)
+        self.phase(rid, t, PH_QUEUE, at=at)
 
     def global_event(self, t: float, name: str, **attrs):
         if self.cfg.events:
+            t = self._stamp(t, attrs)
             self.global_events.append((t, name, attrs or None))
+
+    def first_token(self, rid: int, t: float):
+        """A token left the serving loop: stamps the request's first
+        (under a WallClock; ``finish`` takes the request's own event
+        time otherwise)."""
+        tr = self._live.get(rid)
+        if tr is not None and tr.first_token_t is None:
+            tr.first_token_t = self._stamp(t, {})
 
     def finish(self, req, t: float):
         """Seal a request's trace at its terminal state.  A request the
@@ -190,6 +369,7 @@ class Tracer:
         it still gets a (degenerate) trace, so "every terminal request
         has a trace" holds unconditionally."""
         rid = req.rid
+        t = self._stamp(t, {})
         tr = self._live.pop(rid, None)
         if tr is None:
             if rid in self._done:
@@ -205,7 +385,8 @@ class Tracer:
         tr.state = getattr(state, "value", state)
         tr.finish_reason = getattr(req, "finish_reason", None)
         tr.arrival = getattr(req, "arrival", tr.t_begin)
-        tr.first_token_t = getattr(req, "first_token_time", None)
+        if self.clock is None:
+            tr.first_token_t = getattr(req, "first_token_time", None)
         tr.output_len = getattr(req, "output_len", 0)
         tr.n_recoveries = getattr(req, "n_recoveries", 0)
         self._done[rid] = tr
@@ -314,13 +495,50 @@ class Tracer:
                      "recovered_violators": n_tpot_rec},
         }
 
+    def wait_report(self, lo: float = float("-inf"),
+                    hi: float = float("inf")) -> dict:
+        """Where requests wait before their first token, on the tracer's
+        clock (wall time in live serving).  Over the retained requests
+        received in [lo, hi]: the median seconds from receipt to routing
+        (``route_wait_s``: admission and routing phases) and from routing
+        to the first prefill dispatch (``prefill_queue_s``).  Over the
+        dispatches in [lo, hi]: the event-clock lag, wall minus event
+        time (``event_clock_lag_s``, and the medians of the first and
+        last tenth).  None where nothing was seen."""
+        route, queue = [], []
+        for tr in self.traces():
+            if not lo <= tr.t_begin <= hi:
+                continue
+            t_route = None
+            for sp in tr.spans:
+                if sp.phase == PH_QUEUE and t_route is None:
+                    t_route = sp.t0
+                    route.append(t_route - tr.t_begin)
+                elif sp.phase == PH_PREFILL:
+                    if t_route is not None:
+                        queue.append(sp.t0 - t_route)
+                    break
+        lags = [w - t for w, t in self.lag if lo <= w <= hi]
+        tenth = max(1, len(lags) // 10)
+
+        def median(xs):
+            return statistics.median(xs) if xs else None
+
+        return {"requests": len(route), "route_wait_s": median(route),
+                "prefill_queue_s": median(queue), "dispatches": len(lags),
+                "event_clock_lag_s": median(lags),
+                "lag_first_tenth_s": median(lags[:tenth]),
+                "lag_last_tenth_s": median(lags[-tenth:])}
+
     # ------------------------------------------------------------------
     # exporters
     # ------------------------------------------------------------------
     def to_chrome_trace(self) -> dict:
         """Chrome-trace/Perfetto JSON: one row (tid) per request under
         pid 1, cluster-scoped events under pid 2 (one row per
-        instance).  Times in microseconds as the format requires."""
+        instance), and in live serving, where they share the requests'
+        clock, step spans under pid 3 (one row per instance).  Times in
+        microseconds as the format requires."""
         evs: List[dict] = [
             {"ph": "M", "pid": 1, "tid": 0, "name": "process_name",
              "args": {"name": "requests"}},
@@ -352,6 +570,15 @@ class Tracer:
             if attrs:
                 ev["args"] = attrs
             evs.append(ev)
+        if self.clock is not None:
+            evs.append({"ph": "M", "pid": 3, "tid": 0,
+                        "name": "process_name", "args": {"name": "steps"}})
+            for name, t0, t1, attrs in self.steps:
+                evs.append({"ph": "X", "pid": 3, "tid": attrs.get("iid", 0),
+                            "cat": "step", "name": name,
+                            "ts": round(t0 * 1e6, 3),
+                            "dur": round((t1 - t0) * 1e6, 3),
+                            "args": attrs})
         return {"traceEvents": evs, "displayTimeUnit": "ms"}
 
     def dump_chrome(self, path: str):
@@ -360,8 +587,10 @@ class Tracer:
 
     def dump_jsonl(self, path: str):
         """Flat JSONL event log: one ``meta`` line per request, then its
-        spans and events; global events last.  Grep-able and streamable
-        where the Chrome JSON is a single document."""
+        spans and events; global events, step spans (``step``) and
+        event-clock lag samples (``lag``: tracer clock ``t`` and
+        ``event_t``) last.  Grep-able and streamable where the Chrome
+        JSON is a single document."""
         with open(path, "w") as f:
             for tr in sorted(self.traces(), key=lambda r: r.rid):
                 f.write(json.dumps({
@@ -390,6 +619,12 @@ class Tracer:
                 if attrs:
                     rec["attrs"] = attrs
                 f.write(json.dumps(rec) + "\n")
+            for name, t0, t1, attrs in self.steps:
+                f.write(json.dumps({"kind": "step", "name": name, "t0": t0,
+                                    "t1": t1, "attrs": attrs}) + "\n")
+            for t, ev_t in self.lag:
+                f.write(json.dumps({"kind": "lag", "t": t,
+                                    "event_t": ev_t}) + "\n")
 
 
 # ----------------------------------------------------------------------

@@ -499,9 +499,9 @@ def test_warm_restore_resumes_from_checkpoint():
     orig_sinks = {i.iid: i.token_sink for i in loop.cluster.instances}
 
     def counting(iid):
-        def sink(req, t):
+        def sink(req, t, tok):
             emitted[req.rid] = emitted.get(req.rid, 0) + 1
-            orig_sinks[iid](req, t)
+            orig_sinks[iid](req, t, tok)
         return sink
     for i in loop.cluster.instances:
         i.token_sink = counting(i.iid)
@@ -550,9 +550,9 @@ def test_warm_chaos_no_request_lost_and_token_exact(seed):
     orig_sinks = {i.iid: i.token_sink for i in loop.cluster.instances}
 
     def counting(iid):
-        def sink(req, t):
+        def sink(req, t, tok):
             emitted[req.rid] = emitted.get(req.rid, 0) + 1
-            orig_sinks[iid](req, t)
+            orig_sinks[iid](req, t, tok)
         return sink
     for i in loop.cluster.instances:
         i.token_sink = counting(i.iid)

@@ -652,13 +652,17 @@ def test_http_healthz_reports_per_instance_health(server):
 
 
 class _TokenEchoExecutor(SimExecutor):
-    """Sim oracle that also emits one byte token per decode step, so the
-    SSE path streams real mid-generation frames (the live-engine shape)
-    without any accelerator work."""
+    """Sim oracle that also emits one byte token per generated token (a
+    prefill's last chunk and each decode step, like the live engine), so
+    the SSE path streams real mid-generation frames without any
+    accelerator work."""
 
     def step_async(self, plan):
+        for req, _, _, completes in plan.prefill_rows():
+            if completes:
+                req.output_tokens.append(65)  # "A"
         for req in plan.decode_reqs:
-            req.output_tokens.append(65)      # "A"
+            req.output_tokens.append(65)
         return super().step_async(plan)
 
 

@@ -180,7 +180,7 @@ def test_horizon_timestamps_spread_like_k1(monkeypatch):
     inst.run_iteration(0.0)                      # prefill + first token
     inst.admit_decode(req)
     sink = []
-    inst.token_sink = lambda r, t: sink.append(t)
+    inst.token_sink = lambda r, t, tok: sink.append(t)
     dur, _, _ = inst.run_iteration(1.0)
     assert inst.last_horizon == 4 and len(sink) == 4
     assert all(b > a for a, b in zip(sink, sink[1:]))

@@ -4,6 +4,7 @@ trace / JSONL / Prometheus exporters, the controller decision audit
 trail, the sync-path watchdog heartbeat, and telemetry snapshot
 consistency under concurrent readers."""
 import itertools
+import time
 import json
 import threading
 
@@ -392,3 +393,108 @@ def test_admission_released_by_class_counter():
     assert q.released_by_class["batch"] == 0
     g = q.gauges(1.0)
     assert g["released_by_class"]["interactive"] == 1
+
+
+# ---------------------------------------------------------------------------
+# live serving: wall-clock stamps, the routing/queue split, step spans
+# ---------------------------------------------------------------------------
+
+def _slow_wall_loop(tracing, n=10, gap=0.05, slow=2.0):
+    """A WallClock-paced async loop whose executor takes ``slow`` times
+    each step's modelled duration, so the event clock falls behind the
+    wall as steps run."""
+    from repro.engine.engine import ImmediateStep, SimExecutor
+    from repro.serving import WallClock
+
+    class SlowExecutor(SimExecutor):
+        def __init__(self, inst):
+            self.inst = inst
+
+        def step_async(self, plan):
+            time.sleep(slow * self.inst.iteration_duration(plan))
+            return ImmediateStep()
+
+    reqs = [Request(prompt_len=256, max_new_tokens=8, hidden_output_len=8,
+                    arrival=0.2 + i * gap) for i in range(n)]
+    loop = _mk_loop(arrivals=iter(reqs), steal=False, async_exec=True,
+                    clock=WallClock(), pace=True, tracing=tracing)
+    for inst in loop.cluster.instances:
+        inst.executor = SlowExecutor(inst)
+    finished_at = {}
+    on_finish = loop.cluster.on_finish
+
+    def stamp_finish(req, t):
+        finished_at[req.rid] = loop.clock.now
+        on_finish(req, t)
+    loop.cluster.on_finish = stamp_finish
+    loop.run()
+    return loop, finished_at
+
+
+def test_wall_clock_phases_route_wait_and_lag():
+    """Under a WallClock the phases are wall time: they sum to receipt ->
+    finish as seen from outside the tracer, ``routing`` holds the wait
+    in the event heap (ended by the ``route`` event), and the event
+    clock's lag behind the wall grows while the executor runs slower
+    than the cost model."""
+    loop, finished_at = _slow_wall_loop(TraceConfig())
+    tr = loop.tracer
+    assert all(r.state == State.FINISHED for r in loop.requests)
+    routing = []
+    for r in loop.requests:
+        t = tr.get(r.rid)
+        assert t.t_begin == r.arrival            # the receipt
+        for a, b in zip(t.spans, t.spans[1:]):
+            assert a.t1 == b.t0
+        assert abs(sum(tr.breakdown(r.rid).values())
+                   - (finished_at[r.rid] - r.arrival)) < 1e-3
+        phases = [sp.phase for sp in t.spans]
+        assert phases[:4] == ["routing", "queue", "prefill", "decode_wait"]
+        route = next((tt, a) for tt, n, a in t.events if n == "route")
+        assert t.spans[0].t1 == route[0]          # routing ends at route
+        assert route[1]["event_t"] <= route[0]    # event time runs behind
+        routing.append(t.spans[0].t1 - t.spans[0].t0)
+        assert t.first_token_t > t.spans[2].t0    # wall, at the sink
+        assert abs(sum(tr.ttft_breakdown(r.rid).values())
+                   - (t.first_token_t - t.t_begin)) < 1e-9
+        chunk = next(a for _, n, a in t.events if n == "prefill_chunk")
+        assert {"iid", "seq", "event_t"} <= set(chunk)
+    assert routing[-1] > routing[0] and routing[-1] > 0.05
+    rep = tr.wait_report()
+    assert rep["requests"] == len(loop.requests)
+    assert rep["lag_last_tenth_s"] > rep["lag_first_tenth_s"] + 0.05
+    ttft = [tr.get(r.rid).first_token_t - r.arrival for r in loop.requests]
+    assert 0 < rep["route_wait_s"] <= max(ttft)
+    assert 0 <= rep["prefill_queue_s"] <= max(ttft)
+    # step spans: plan, dispatch and commit for every dispatched step
+    names = {}
+    for name, t0, t1, attrs in tr.steps:
+        assert t1 >= t0 and "iid" in attrs
+        names[name] = names.get(name, 0) + 1
+    assert names["taichi.step.dispatch"] == len(tr.lag)
+    assert names["taichi.step.commit"] == names["taichi.step.dispatch"]
+    assert names["taichi.step.plan"] >= names["taichi.step.dispatch"]
+    snap = loop.snapshot()
+    assert snap["event_clock_lag_s"] > 0
+
+
+def test_tracing_off_makes_no_span_or_annotation(monkeypatch):
+    from repro.serving import tracing
+    made = {"spans": 0, "annotations": 0}
+    real_init, real_ann = tracing.StepSpan.__init__, tracing._annotation
+
+    def init(self, *a):
+        made["spans"] += 1
+        real_init(self, *a)
+
+    def ann(*a):
+        made["annotations"] += 1
+        return real_ann(*a)
+    monkeypatch.setattr(tracing.StepSpan, "__init__", init)
+    monkeypatch.setattr(tracing, "_annotation", ann)
+    loop, _ = _slow_wall_loop(None, n=3)
+    assert loop.tracer is None
+    assert all(r.state == State.FINISHED for r in loop.requests)
+    assert made == {"spans": 0, "annotations": 0}
+    _slow_wall_loop(TraceConfig(), n=3)
+    assert made["spans"] > 0 and made["annotations"] > 0
