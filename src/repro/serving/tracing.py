@@ -83,6 +83,8 @@ import time
 from collections import deque
 from typing import Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 PH_ADMISSION = "admission"
 PH_ROUTING = "routing"
 PH_QUEUE = "queue"
@@ -194,7 +196,7 @@ class StepSpan:
         self._ann = None
 
     def __enter__(self):
-        self._ann = _annotation(self.name, self.attrs)
+        self._ann = TraceAnnotation(self.name, **self.attrs)
         self._ann.__enter__()
         self.t0 = self.tracer.now()
         return self
@@ -204,18 +206,6 @@ class StepSpan:
         self._ann.__exit__(*exc)
         self.tracer.steps.append((self.name, self.t0, t1, self.attrs))
         return False
-
-
-_TraceAnnotation = None
-
-
-def _annotation(name: str, attrs: dict):
-    """A ``jax.profiler.TraceAnnotation`` (JAX imported on first use)."""
-    global _TraceAnnotation
-    if _TraceAnnotation is None:
-        from jax.profiler import TraceAnnotation
-        _TraceAnnotation = TraceAnnotation
-    return _TraceAnnotation(name, **attrs)
 
 
 class Tracer:
@@ -284,7 +274,7 @@ class Tracer:
         (a no-op when no profile runs), carrying this clock's reading
         at its start in integer nanoseconds (``t_ns``)."""
         t_ns = int(self.now() * 1e9)
-        with _annotation(CLOCK_SYNC, {"t_ns": t_ns}):
+        with TraceAnnotation(CLOCK_SYNC, t_ns=t_ns):
             pass
 
     @staticmethod

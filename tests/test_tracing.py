@@ -481,17 +481,17 @@ def test_wall_clock_phases_route_wait_and_lag():
 def test_tracing_off_makes_no_span_or_annotation(monkeypatch):
     from repro.serving import tracing
     made = {"spans": 0, "annotations": 0}
-    real_init, real_ann = tracing.StepSpan.__init__, tracing._annotation
+    real_init, real_ann = tracing.StepSpan.__init__, tracing.TraceAnnotation
 
     def init(self, *a):
         made["spans"] += 1
         real_init(self, *a)
 
-    def ann(*a):
+    def ann(*a, **kw):
         made["annotations"] += 1
-        return real_ann(*a)
+        return real_ann(*a, **kw)
     monkeypatch.setattr(tracing.StepSpan, "__init__", init)
-    monkeypatch.setattr(tracing, "_annotation", ann)
+    monkeypatch.setattr(tracing, "TraceAnnotation", ann)
     loop, _ = _slow_wall_loop(None, n=3)
     assert loop.tracer is None
     assert all(r.state == State.FINISHED for r in loop.requests)
