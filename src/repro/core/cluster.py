@@ -22,13 +22,26 @@ dropped) and the flip lands once the decode side is empty.
 
 Async execution (``async_exec=True``): each ITER splits into a DISPATCH
 (the instance hands the plan to its executor's non-blocking
-``step_async`` and the cluster schedules a COMMIT at the modeled end
-time) and a COMMIT (the single host readback, bookkeeping, then —
-before the host spends time streaming the tokens — the NEXT iteration
-is dispatched inline, so the device computes horizon N+1 while the host
-consumes horizon N).  Migrations, drains, and flips all run in an
-instance's commit phase, i.e. with its pipeline flushed — an eject can
-never observe a half-applied horizon.
+``step_async``) and a COMMIT (the single host readback, bookkeeping,
+then — before the host spends time streaming the tokens — the NEXT
+iteration is dispatched inline, so the device computes horizon N+1
+while the host consumes horizon N).  Who times the COMMIT depends on
+what can be observed:
+
+* modelled: the cluster schedules a COMMIT event at the modeled end
+  time (dispatch + ``CostModel`` duration).  This is every run on a
+  virtual clock, and any step the executor returns as an
+  ``ImmediateStep`` (the simulator's oracle, a paced demo);
+* device-timed: on a wall clock (``device_clock``, set by the serving
+  loop) a device step (``PendingStep``) goes on the ``in_flight`` list,
+  in dispatch order, and no COMMIT is scheduled.  The serving loop
+  commits it through ``commit_device`` once the device has finished it,
+  at that wall time, and advances the event clock to the wall before
+  each heap event it handles.
+
+Migrations, drains, and flips all run in an instance's commit phase,
+i.e. with its pipeline flushed — an eject can never observe a
+half-applied horizon.
 
 Fault tolerance: ``fail_instance`` (crash, total HBM/KV loss) and
 ``quarantine_instance`` (suspected-bad, memory kept) evacuate every
@@ -47,14 +60,17 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import itertools
+import statistics
 import traceback
-from typing import Callable, Dict, List, Optional, Sequence
+from collections import deque
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.estimator import CostModel
 from repro.core.instance import (D_HEAVY, HEALTH_DEAD, HEALTH_OK,
                                  HEALTH_QUARANTINED, Instance)
 from repro.core.latency import SLO, RunStats
 from repro.core.policies import BasePolicy
+from repro.engine.engine import PendingStep
 from repro.engine.request import Request, State, TERMINAL_STATES
 from repro.serving import faults as flt
 from repro.serving.recovery import RecoveryConfig, RecoveryManager
@@ -94,6 +110,15 @@ class Cluster:
     #: warm-recovery manager (checkpoints + post-crash re-replication);
     #: None = every recovery site below is inert
     recovery: Optional[RecoveryManager] = None
+    #: the serving loop runs on a wall clock: device steps are committed
+    #: when the device finishes them (set by ``ServingLoop``)
+    device_clock: bool = False
+    #: a device step has been dispatched on that clock: device steps set
+    #: the cluster's clock from then on (the serving loop keeps the
+    #: event clock on the wall)
+    device_timed: bool = False
+    #: device-timed commit leads kept for the snapshot's median
+    LEAD_KEPT = 4096
 
     def __init__(self, policy: BasePolicy, cost: CostModel,
                  async_exec: bool = False,
@@ -143,6 +168,13 @@ class Cluster:
         self.failed_count = 0
         self.aborted_count = 0
         self.last_exec_error: Optional[str] = None
+        # commit timing: device steps awaiting the device, as
+        # (instance, step) in dispatch order; how many commits each
+        # path made; device end minus modelled end (s) per device commit
+        self.in_flight: List[Tuple[Instance, PendingStep]] = []
+        self.device_timed_commits = 0
+        self.model_timed_commits = 0
+        self.commit_model_lead: deque = deque(maxlen=self.LEAD_KEPT)
 
     # ------------------------------------------------------------------
     def _push(self, t: float, kind: int, data):
@@ -217,22 +249,22 @@ class Cluster:
     def peek_time(self) -> Optional[float]:
         return self._heap[0][0] if self._heap else None
 
-    def peek_event(self) -> Optional[tuple]:
-        """Next event as ``(time, kind, data)`` without popping — the
-        serving loop's wall-clock watchdog inspects an imminent COMMIT
-        to decide whether its ``PendingStep`` is overdue."""
-        if not self._heap:
-            return None
-        t, _, kind, data = self._heap[0]
-        return t, kind, data
-
-    def step(self) -> Optional[tuple]:
+    def step(self, now: Optional[float] = None) -> Optional[tuple]:
         """Pop and process exactly one event.  Returns ``(time, kind,
-        data)`` for observability, or None when the heap is empty."""
+        data)`` for observability, or None when the heap is empty.
+
+        With ``now`` (the wall, on the device-timed path) the event
+        clock first advances to it, and the event is handled at the
+        latest of that, the event clock and the event's own time (a
+        lower bound); without it the event is handled at its time."""
         if not self._heap:
             return None
-        now, _, kind, data = heapq.heappop(self._heap)
-        self.now = max(self.now, now)
+        t, _, kind, data = heapq.heappop(self._heap)
+        if now is None:
+            now = t
+            self.now = max(self.now, t)
+        else:
+            now = self.now = max(self.now, t, now)
         self._handle(now, kind, data)
         if self._aborting:
             self._sweep_aborts(self.now)
@@ -709,10 +741,58 @@ class Cluster:
         # hold the scheduled flag through the flight so arrivals and
         # transfers cannot double-dispatch; the commit rearms it
         self._iter_scheduled[inst.iid] = True
-        self._push(now + dur, COMMIT, inst.iid)
+        pending = inst.pending_step()
+        if self.device_clock and isinstance(pending, PendingStep):
+            self.in_flight.append((inst, pending))
+            self.device_timed = True
+        else:
+            self._push(now + dur, COMMIT, inst.iid)
 
-    def _commit(self, inst: Instance, now: float):
-        res = inst.commit_iteration(defer_emit=True)
+    def ready_in_flight(self) -> List[Tuple[Instance, PendingStep]]:
+        """In-flight device steps that ``commit_device`` can take now,
+        in dispatch order: those the device has finished, and those a
+        failure or quarantine discarded since their dispatch."""
+        return [(inst, p) for inst, p in self.in_flight
+                if inst.pending_step() is not p or p.ready()]
+
+    def commit_device(self, inst: Instance, pending: PendingStep,
+                      now: float):
+        """Commit an in-flight device step at ``now``, the wall time at
+        which the device was seen to have finished it (the event clock
+        advances to it).  A step discarded since its dispatch is
+        dropped, as a stale COMMIT is."""
+        self.in_flight.remove((inst, pending))
+        now = self.now = max(self.now, now)
+        if inst.pending_step() is not pending:
+            self._iter_scheduled[inst.iid] = False
+            if inst.has_work():
+                self._schedule_iter(inst, now)
+            return
+        try:
+            self._commit(inst, now, device=True)
+        except Exception as e:             # device/readback failure
+            self._on_exec_error(inst, now, e)
+        if self._aborting:
+            self._sweep_aborts(self.now)
+
+    def lead_median(self) -> Optional[float]:
+        """Median device end minus modelled end (s) over the kept
+        device-timed commits; None before the first."""
+        if not self.commit_model_lead:
+            return None
+        return statistics.median(self.commit_model_lead)
+
+    def _commit(self, inst: Instance, now: float, device: bool = False):
+        res = inst.commit_iteration(defer_emit=True,
+                                    end=now if device else None)
+        if device:
+            lead = now - res.model_end
+            self.device_timed_commits += 1
+            self.commit_model_lead.append(lead)
+            if self.tracer is not None:
+                self.tracer.commit_lead(now, lead)
+        else:
+            self.model_timed_commits += 1
         self._iter_scheduled[inst.iid] = False
         # scheduling first (migrations/drains run against a flushed
         # pipeline), then dispatch the NEXT iteration inline so the

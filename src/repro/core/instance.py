@@ -66,11 +66,14 @@ class CommitResult:
     dispatch/commit split).  ``token_events`` carries the deferred
     per-token sink calls ``(request, time, token id or None)`` when the
     caller asked for deferred emission — so it can dispatch the next
-    horizon before the host spends time streaming these."""
+    horizon before the host spends time streaming these.
+    ``model_end`` is where the cost model put the step's end (dispatch
+    plus modelled duration), whatever end the commit was stamped at."""
     duration: float
     prefill_done: List[Request]
     finished: List[Request]
     token_events: List[Tuple[Request, float, Optional[int]]]
+    model_end: float
 
 
 class Executor(Protocol):
@@ -646,19 +649,27 @@ class Instance:
             return None
         return pending
 
-    def commit_iteration(self, defer_emit: bool = False) -> CommitResult:
+    def commit_iteration(self, defer_emit: bool = False,
+                         end: Optional[float] = None) -> CommitResult:
         """Resolve the in-flight step (the one blocking readback) and
         apply request/latency bookkeeping.  With ``defer_emit`` the
         per-token sink callbacks are returned instead of fired, so the
         caller can dispatch the next horizon first and stream these
-        while the device computes (one-horizon-lagged consumption)."""
+        while the device computes (one-horizon-lagged consumption).
+
+        ``end`` is when the step ended: the wall time at which the
+        device finished it, on the live loop's device-timed path.
+        Without it the step ends where the cost model put it (dispatch
+        plus modelled duration).  Tokens, finish times and
+        ``busy_until`` are stamped at that end."""
         tr = self.tracer
         if tr is None:
-            return self._commit_iteration(defer_emit, None)
+            return self._commit_iteration(defer_emit, None, end)
         with tr.step(STEP_COMMIT, iid=self.iid, seq=self._inflight_seq):
-            return self._commit_iteration(defer_emit, tr)
+            return self._commit_iteration(defer_emit, tr, end)
 
-    def _commit_iteration(self, defer_emit: bool, tr) -> CommitResult:
+    def _commit_iteration(self, defer_emit: bool, tr,
+                          end: Optional[float]) -> CommitResult:
         plan, pending, t0, dur = self._inflight
         seq, wall = self._inflight_seq, self._inflight_wall
         # resolve BEFORE discarding the in-flight record: if the
@@ -674,7 +685,10 @@ class Instance:
             eos = self.executor.execute(plan)
             emitted = {}
         self._inflight = None
-        end = t0 + dur
+        model_end = t0 + dur
+        device_end = end is not None
+        if not device_end:
+            end = model_end
         events: List[Tuple[Request, float, Optional[int]]] = []
 
         def emit(req, t):
@@ -729,11 +743,22 @@ class Instance:
                   for r, b in zip(plan.decode_reqs, budgets)]
         # spread horizon token timestamps over the modeled per-step
         # durations, exactly where a K=1 schedule would have put them
-        # (in-flight TPOT telemetry reads per-step latency, not dur/1)
+        # (in-flight TPOT telemetry reads per-step latency, not dur/1):
+        # forward from the dispatch, or back from a device-timed end
+        step_t = [end] * K
+        if K > 1 and device_end:
+            t = end
+            for s in range(K - 1, -1, -1):
+                step_t[s] = t
+                t -= plan.step_durations[s]
+        elif K > 1:
+            t = t0
+            for s in range(K):
+                t += plan.step_durations[s]
+                step_t[s] = t
         last_t = [end] * len(plan.decode_reqs)
-        t = t0
         for s in range(K):
-            t = end if K == 1 else t + plan.step_durations[s]
+            t = step_t[s]
             for i, (req, c) in enumerate(zip(plan.decode_reqs, counts)):
                 if s >= c:
                     continue
@@ -764,7 +789,7 @@ class Instance:
         self.busy_until = end
         self.last_progress = end
         self.step_deadline = float("inf")
-        return CommitResult(dur, prefill_done, finished, events)
+        return CommitResult(dur, prefill_done, finished, events, model_end)
 
     @staticmethod
     def _finish_reason(req: Request) -> str:

@@ -9,11 +9,18 @@ relate to the caller's experience of time:
   simulator run on this.
 * ``WallClock`` — the loop *paces* itself to real time: before
   processing an event at virtual time ``t`` it sleeps until ``t``
-  seconds after the epoch anchor.  This is the live-demo mode where
-  streamed tokens arrive at the modeled rate.  If event processing
-  (e.g. real JAX execution) already took longer than the modeled
-  duration, no sleep happens — the loop simply runs behind, exactly
-  like an overloaded server.
+  seconds after the epoch anchor.  Who sets the rate at which tokens
+  stream depends on the executor's steps:
+
+  - device steps (``PendingStep``, the real engine): each step is
+    committed when the device has finished it, at that wall time, and
+    the event clock follows the wall, so tokens stream at the rate the
+    device computes them;
+  - ``ImmediateStep`` (the simulator's oracle): each step commits at
+    its modeled end, so this is the live-demo mode where streamed
+    tokens arrive at the modeled rate.  If event processing already
+    took longer than the modeled duration, no sleep happens — the loop
+    simply runs behind, exactly like an overloaded server.
 """
 from __future__ import annotations
 

@@ -21,7 +21,11 @@ the heap drains — fine for goodput sweeps, useless for serving.
 The loop is executor-agnostic: with ``SimExecutor`` it is a
 deterministic virtual-clock simulation; with ``JaxExecutor`` the same
 schedule computes real tokens (``--engine live``), optionally paced to
-wall time by ``WallClock``.
+wall time by ``WallClock``.  On a ``WallClock`` the device times the
+steps it runs: each in-flight device step is committed when the device
+has finished it, at that wall time, and the event clock follows the
+wall (``Cluster.commit_device``); steps the executor returns as
+``ImmediateStep`` keep their cost-model COMMIT times.
 """
 from __future__ import annotations
 
@@ -52,9 +56,9 @@ class WatchdogConfig:
     ``heartbeat_timeout`` is EVENT time: an instance whose dispatched
     step runs this far past its cost-model deadline (``step_deadline``)
     is quarantined — injected stalls and real slowdowns both trip it.
-    ``stall_timeout`` is WALL time (live executors only): a COMMIT whose
-    ``PendingStep`` still isn't ready this long past the modeled end
-    quarantines the instance instead of blocking the loop on
+    ``stall_timeout`` is WALL time (live executors only): the oldest
+    in-flight ``PendingStep`` still not ready this long past the modeled
+    end quarantines the instance instead of blocking the loop on
     ``resolve()``.  Quarantined instances re-admit after ``probation``
     seconds, doubling per repeat offense up to ``max_probation``."""
     heartbeat_timeout: float = 2.0
@@ -218,9 +222,12 @@ class ServingLoop:
     @clock.setter
     def clock(self, clock):
         """Swapping the clock rebinds the tracer's: it reads a
-        ``WallClock`` at each hook and keeps event time otherwise."""
+        ``WallClock`` at each hook and keeps event time otherwise.  On a
+        ``WallClock`` the cluster commits device steps when the device
+        has finished them."""
         self._clock = clock
         self._wall = clock if isinstance(clock, WallClock) else None
+        self.cluster.device_clock = self._wall is not None
         if self.tracer is not None:
             self.tracer.clock = self._wall
 
@@ -591,22 +598,20 @@ class ServingLoop:
                                                  iid=inst.iid)
 
     def _stall_check(self) -> bool:
-        """Live-path stall guard: when the next event is a COMMIT whose
-        async step STILL is not device-ready ``stall_timeout`` wall
-        seconds past its modeled end, quarantine the instance instead of
-        letting ``PendingStep.resolve`` block the loop forever.  Returns
-        True when it intervened (the caller re-peeks: the COMMIT is now
-        stale and the evacuated work has been rerouted)."""
+        """Live-path stall guard: when the oldest in-flight device step
+        STILL is not device-ready ``stall_timeout`` wall seconds past
+        its modeled end, quarantine the instance instead of letting
+        ``PendingStep.resolve`` block the loop forever.  (Under a
+        ``WallClock`` every device step is in flight there; a COMMIT
+        event carries a step that is always ready.)  Returns True when
+        it intervened (the caller re-peeks: the step is now stale and
+        the evacuated work has been rerouted)."""
         wd = self.watchdog
-        if wd is None or not isinstance(self.clock, WallClock):
+        if wd is None or not self.cluster.in_flight:
             return False
-        ev = self.cluster.peek_event()
-        if ev is None or ev[1] != COMMIT:
-            return False
-        inst = self.cluster._inst_by_id[ev[2]]
-        pending = inst.pending_step()
-        if pending is None or pending.ready():
-            return False
+        inst, pending = self.cluster.in_flight[0]
+        if inst.pending_step() is not pending or pending.ready():
+            return False               # done, or discarded: committed next
         if self.clock.now < inst.step_deadline + wd.stall_timeout:
             return False
         self._quarantine(inst, self.cluster.now, "stall")
@@ -661,16 +666,20 @@ class ServingLoop:
                 p.prefetch()
 
     def _pace_until(self, t: float) -> bool:
-        """Sleep to the next event time WITHOUT serializing ingestion
-        behind compute: instead of one dead sleep, the gap is sliced and
-        each slice polls the in-flight executor steps — the moment a
-        horizon's device work completes, its results are prefetched to
-        the host, so the commit event at ``t`` never blocks.  The wait
+        """The modelled path's wait: sleep to the next event time
+        WITHOUT serializing ingestion behind compute.  Instead of one
+        dead sleep, the gap is sliced and each slice polls the in-flight
+        executor steps — the moment a horizon's device work completes,
+        its results are prefetched to the host, so the commit event at
+        ``t`` (the cost model's end of the step) never blocks.  The wait
         ends on whichever comes first: the next scheduled event
-        (arrival/commit/transfer), in-flight work becoming consumable,
-        or a NEW network ingress submission (which may schedule an
-        earlier arrival than ``t`` — the caller must re-peek).  Returns
-        False when preempted by ingress, True when ``t`` was reached."""
+        (arrival/commit/transfer), or a NEW network ingress submission
+        (which may schedule an earlier arrival than ``t`` — the caller
+        must re-peek).  Returns False when preempted by ingress, True
+        when ``t`` was reached.  On the device-timed path no COMMIT
+        waits in the heap for a device step: the loop waits in
+        ``_await_device`` instead, and commits each step as soon as the
+        device has finished it."""
         pending = self._pending_steps()
         slice_wait = isinstance(self.clock, WallClock) \
             and (pending or self._ingress is not None)
@@ -697,6 +706,46 @@ class ServingLoop:
                 if now >= t:
                     return True
 
+    #: wall-clock slice between readiness polls while device steps are
+    #: in flight: a finished step waits at most this long to be committed
+    DEVICE_POLL = 0.001
+
+    def _await_device(self, t: Optional[float]):
+        """The device-timed path's wait.  Returns on whichever comes
+        first: an in-flight step is done (the device finished it, or it
+        was discarded), the heap's next event falls due at ``t`` (None:
+        the heap is empty), a network ingress submission, or the oldest
+        step's stall deadline (with a watchdog, so ``_stall_check`` can
+        act).  Polls every ``DEVICE_POLL`` while steps are in flight or
+        ingress may arrive."""
+        cl = self.cluster
+        end = float("inf") if t is None else t
+        if cl.in_flight and self.watchdog is not None:
+            end = min(end, cl.in_flight[0][0].step_deadline
+                      + self.watchdog.stall_timeout)
+        if cl.ready_in_flight() or self._ingress_pending():
+            return
+        poll = cl.in_flight or self._ingress is not None
+        with (NO_SPAN if self.tracer is None else self.tracer.step(
+                LOOP_PACE, **({} if t is None else {"event_t": t}))):
+            while True:
+                now = self.clock.now
+                if now >= end:
+                    return
+                self.clock.sleep_until(
+                    min(end, now + self.DEVICE_POLL) if poll else end)
+                if cl.ready_in_flight() or self._ingress_pending():
+                    return
+
+    def _commit_ready(self) -> int:
+        """Commit every in-flight device step the device has finished,
+        in dispatch order, each at the wall time it is committed.
+        Returns how many were taken."""
+        done = self.cluster.ready_in_flight()
+        for inst, pending in done:
+            self.cluster.commit_device(inst, pending, self.clock.now)
+        return len(done)
+
     # ------------------------------------------------------------------
     # the loop
     # ------------------------------------------------------------------
@@ -712,35 +761,58 @@ class ServingLoop:
         while max_steps is None or steps < max_steps:
             self._drain_ingress()
             self._watchdog_check(self.cluster.now)
+            # device-timed: device steps set the clock, and the event
+            # clock follows the wall (heap times are lower bounds)
+            device = self._wall is not None and self.cluster.device_timed
+            if device:
+                n = self._commit_ready()
+                if n:
+                    steps += n
+                    self._after_event(COMMIT)
+                    continue
+                if self._stall_check():
+                    continue          # quarantined a stalled step
             t = self.cluster.peek_time()
             if t is None:
+                if device and self.cluster.in_flight:
+                    self._await_device(None)
+                    continue
                 if not self._pump_arrival():
                     break
                 continue
             if until is not None and t > until:
                 break
-            if self._pace and not self._pace_until(t):
-                continue              # ingress preempted: re-peek
-            if self._stall_check():
-                continue              # quarantined a stalled step: re-peek
-            stepped = self.cluster.step()
+            if device:
+                if self._pace and self.clock.now < t:
+                    self._await_device(t)
+                    continue          # re-check commits, re-peek
+                stepped = self.cluster.step(self.clock.now)
+            else:
+                if self._pace and not self._pace_until(t):
+                    continue          # ingress preempted: re-peek
+                stepped = self.cluster.step()
             if stepped is None:
                 continue
             steps += 1
-            _, kind, _ = stepped
-            if kind == ARRIVAL:
-                self._pump_arrival()
-            elif self._steal:
-                self._steal_prefill()
-            now = self.cluster.now
-            if self.controller is not None:
-                self.controller.maybe_epoch(now)
-            if self._snapshot_every is not None \
-                    and now >= self._next_snapshot:
-                self.log.record(self.snapshot(now))
-                self._next_snapshot = (
-                    now - now % self._snapshot_every + self._snapshot_every)
+            self._after_event(stepped[1])
         return steps
+
+    def _after_event(self, kind: int):
+        """Per-event follow-up: keep one arrival pumped (or repair
+        prefill placement), then the controller's epoch and the
+        periodic snapshot."""
+        if kind == ARRIVAL:
+            self._pump_arrival()
+        elif self._steal:
+            self._steal_prefill()
+        now = self.cluster.now
+        if self.controller is not None:
+            self.controller.maybe_epoch(now)
+        if self._snapshot_every is not None \
+                and now >= self._next_snapshot:
+            self.log.record(self.snapshot(now))
+            self._next_snapshot = (
+                now - now % self._snapshot_every + self._snapshot_every)
 
     def snapshot(self, now: Optional[float] = None) -> dict:
         now = self.cluster.now if now is None else now
@@ -757,6 +829,12 @@ class ServingLoop:
             # how far the event clock (estimator time) runs behind the
             # wall: arrivals wait in the heap until it catches up
             snap["event_clock_lag_s"] = self._wall.now - self.cluster.now
+            # which path timed the commits, and how far the device's
+            # ends ran past the cost model's
+            cl = self.cluster
+            snap["device_timed_commits"] = cl.device_timed_commits
+            snap["model_timed_commits"] = cl.model_timed_commits
+            snap["commit_model_lead_s"] = cl.lead_median()
         return snap
 
     # ------------------------------------------------------------------
@@ -780,6 +858,7 @@ class ServingLoop:
             while not stop.is_set():
                 self.run(max_steps=self.SERVE_SLICE)
                 if self.cluster.peek_time() is None \
+                        and not self.cluster.in_flight \
                         and not self._ingress_pending():
                     try:                # idle: wait for the next client
                         self._ingress_msg(ingress.get(timeout=idle_poll))

@@ -234,6 +234,9 @@ class Tracer:
         self.steps: deque = deque(maxlen=self.STEPS_KEPT)
         #: (tracer clock, event time) at each dispatch under a WallClock
         self.lag: deque = deque(maxlen=self.LAG_KEPT)
+        #: (tracer clock, device end minus modelled end) per device-timed
+        #: commit
+        self.leads: deque = deque(maxlen=self.LAG_KEPT)
         self._next_sync = float("-inf")
 
     # ------------------------------------------------------------------
@@ -268,6 +271,11 @@ class Tracer:
             self._next_sync = wall + self.CLOCK_SYNC_EVERY
             self.clock_sync()
         return wall
+
+    def commit_lead(self, t: float, lead: float):
+        """Record one device-timed commit at ``t``: how far the device's
+        end ran past the cost model's (seconds, negative when early)."""
+        self.leads.append((self._stamp(t, {}), lead))
 
     def clock_sync(self):
         """Write a ``taichi.clock`` annotation into the profiler's trace
@@ -494,7 +502,9 @@ class Tracer:
         to the first prefill dispatch (``prefill_queue_s``).  Over the
         dispatches in [lo, hi]: the event-clock lag, wall minus event
         time (``event_clock_lag_s``, and the medians of the first and
-        last tenth).  None where nothing was seen."""
+        last tenth).  Over the device-timed commits in [lo, hi]: the
+        device's end minus the cost model's (``commit_model_lead_s``).
+        None where nothing was seen."""
         route, queue = [], []
         for tr in self.traces():
             if not lo <= tr.t_begin <= hi:
@@ -510,6 +520,7 @@ class Tracer:
                     break
         lags = [w - t for w, t in self.lag if lo <= w <= hi]
         tenth = max(1, len(lags) // 10)
+        leads = [d for w, d in self.leads if lo <= w <= hi]
 
         def median(xs):
             return statistics.median(xs) if xs else None
@@ -518,7 +529,9 @@ class Tracer:
                 "prefill_queue_s": median(queue), "dispatches": len(lags),
                 "event_clock_lag_s": median(lags),
                 "lag_first_tenth_s": median(lags[:tenth]),
-                "lag_last_tenth_s": median(lags[-tenth:])}
+                "lag_last_tenth_s": median(lags[-tenth:]),
+                "device_commits": len(leads),
+                "commit_model_lead_s": median(leads)}
 
     # ------------------------------------------------------------------
     # exporters
