@@ -114,11 +114,13 @@ class Cluster:
     #: when the device finishes them (set by ``ServingLoop``)
     device_clock: bool = False
     #: a device step has been dispatched on that clock: device steps set
-    #: the cluster's clock from then on (the serving loop keeps the
-    #: event clock on the wall)
+    #: the cluster's clock from then on, even after a quarantine has
+    #: discarded every step in flight (the serving loop keeps the event
+    #: clock on the wall)
     device_timed: bool = False
-    #: device-timed commit leads kept for the snapshot's median
-    LEAD_KEPT = 4096
+    #: device-timed commit leads kept for the snapshot's median and the
+    #: tracer's wait report
+    LEAD_KEPT = 65536
 
     def __init__(self, policy: BasePolicy, cost: CostModel,
                  async_exec: bool = False,
@@ -170,7 +172,8 @@ class Cluster:
         self.last_exec_error: Optional[str] = None
         # commit timing: device steps awaiting the device, as
         # (instance, step) in dispatch order; how many commits each
-        # path made; device end minus modelled end (s) per device commit
+        # path made; (commit time, device end minus modelled end in s)
+        # per device commit
         self.in_flight: List[Tuple[Instance, PendingStep]] = []
         self.device_timed_commits = 0
         self.model_timed_commits = 0
@@ -780,17 +783,14 @@ class Cluster:
         device-timed commits; None before the first."""
         if not self.commit_model_lead:
             return None
-        return statistics.median(self.commit_model_lead)
+        return statistics.median(d for _, d in self.commit_model_lead)
 
     def _commit(self, inst: Instance, now: float, device: bool = False):
         res = inst.commit_iteration(defer_emit=True,
                                     end=now if device else None)
         if device:
-            lead = now - res.model_end
             self.device_timed_commits += 1
-            self.commit_model_lead.append(lead)
-            if self.tracer is not None:
-                self.tracer.commit_lead(now, lead)
+            self.commit_model_lead.append((now, now - res.model_end))
         else:
             self.model_timed_commits += 1
         self._iter_scheduled[inst.iid] = False

@@ -201,6 +201,7 @@ class ServingLoop:
             self.tracer = (tracing if isinstance(tracing, Tracer)
                            else Tracer(tracing))
             self.tracer.clock = self._wall
+            self.tracer.leads = cluster.commit_model_lead
             cluster.tracer = self.tracer
             for inst in cluster.instances:
                 inst.tracer = self.tracer
@@ -650,74 +651,35 @@ class ServingLoop:
             self.cluster._schedule_iter(thief, self.cluster.now)
 
     # ------------------------------------------------------------------
-    # pacing: wait on either the next event OR horizon completion
+    # pacing: wait for the next event, a finished device step or ingress
     # ------------------------------------------------------------------
-    #: wall-clock slice between pipeline-readiness polls while pacing
-    PACE_SLICE = 0.005
-
-    def _pending_steps(self):
-        """Unresolved async executor steps currently in flight."""
-        return [p for inst in self.cluster.instances
-                if (p := inst.pending_step()) is not None]
-
-    def _prefetch_ready(self, pending) -> None:
-        for p in pending:
-            if not p.resolved and p.ready():
+    def _pace_until(self, t: float):
+        """The virtual clock's wait: harvest every in-flight executor
+        step the device has already finished, so its COMMIT at ``t``
+        (the cost model's end of the step) does not block, then jump to
+        ``t``.  A wall clock waits in ``_await_device`` instead, on both
+        paths: there a device step is committed as soon as the device
+        has finished it, and a modelled COMMIT carries a step that is
+        always ready."""
+        for inst in self.cluster.instances:
+            p = inst.pending_step()
+            if p is not None and not p.resolved and p.ready():
                 p.prefetch()
-
-    def _pace_until(self, t: float) -> bool:
-        """The modelled path's wait: sleep to the next event time
-        WITHOUT serializing ingestion behind compute.  Instead of one
-        dead sleep, the gap is sliced and each slice polls the in-flight
-        executor steps — the moment a horizon's device work completes,
-        its results are prefetched to the host, so the commit event at
-        ``t`` (the cost model's end of the step) never blocks.  The wait
-        ends on whichever comes first: the next scheduled event
-        (arrival/commit/transfer), or a NEW network ingress submission
-        (which may schedule an earlier arrival than ``t`` — the caller
-        must re-peek).  Returns False when preempted by ingress, True
-        when ``t`` was reached.  On the device-timed path no COMMIT
-        waits in the heap for a device step: the loop waits in
-        ``_await_device`` instead, and commits each step as soon as the
-        device has finished it."""
-        pending = self._pending_steps()
-        slice_wait = isinstance(self.clock, WallClock) \
-            and (pending or self._ingress is not None)
-        if not slice_wait:
-            # virtual time (or nothing that could preempt): plain jump —
-            # but still harvest anything that already landed
-            self._prefetch_ready(pending)
-            self.clock.sleep_until(t)
-            return True
-        self._prefetch_ready(pending)
-        if self._ingress_pending():
-            return False
-        now = self.clock.now
-        if now >= t:
-            return True
-        with (NO_SPAN if self.tracer is None
-              else self.tracer.step(LOOP_PACE, event_t=t)):
-            while True:
-                self.clock.sleep_until(min(t, now + self.PACE_SLICE))
-                self._prefetch_ready(pending)
-                if self._ingress_pending():
-                    return False
-                now = self.clock.now
-                if now >= t:
-                    return True
+        self.clock.sleep_until(t)
 
     #: wall-clock slice between readiness polls while device steps are
     #: in flight: a finished step waits at most this long to be committed
     DEVICE_POLL = 0.001
 
     def _await_device(self, t: Optional[float]):
-        """The device-timed path's wait.  Returns on whichever comes
-        first: an in-flight step is done (the device finished it, or it
-        was discarded), the heap's next event falls due at ``t`` (None:
-        the heap is empty), a network ingress submission, or the oldest
-        step's stall deadline (with a watchdog, so ``_stall_check`` can
-        act).  Polls every ``DEVICE_POLL`` while steps are in flight or
-        ingress may arrive."""
+        """A wall clock's wait, device-timed or modelled.  Returns on
+        whichever comes first: an in-flight step is done (the device
+        finished it, or it was discarded), the heap's next event falls
+        due at ``t`` (None: the heap is empty), a network ingress
+        submission, or the oldest step's stall deadline (with a
+        watchdog, so ``_stall_check`` can act).  Polls every
+        ``DEVICE_POLL`` inside a ``taichi.loop.pace`` span while steps
+        are in flight or ingress may arrive, else sleeps to ``t``."""
         cl = self.cluster
         end = float("inf") if t is None else t
         if cl.in_flight and self.watchdog is not None:
@@ -725,15 +687,16 @@ class ServingLoop:
                       + self.watchdog.stall_timeout)
         if cl.ready_in_flight() or self._ingress_pending():
             return
-        poll = cl.in_flight or self._ingress is not None
+        if not cl.in_flight and self._ingress is None:
+            self.clock.sleep_until(end)    # nothing can cut the wait short
+            return
         with (NO_SPAN if self.tracer is None else self.tracer.step(
                 LOOP_PACE, **({} if t is None else {"event_t": t}))):
             while True:
                 now = self.clock.now
                 if now >= end:
                     return
-                self.clock.sleep_until(
-                    min(end, now + self.DEVICE_POLL) if poll else end)
+                self.clock.sleep_until(min(end, now + self.DEVICE_POLL))
                 if cl.ready_in_flight() or self._ingress_pending():
                     return
 
@@ -782,15 +745,13 @@ class ServingLoop:
                 continue
             if until is not None and t > until:
                 break
-            if device:
-                if self._pace and self.clock.now < t:
+            if self._pace:
+                if self._wall is None:
+                    self._pace_until(t)
+                elif self.clock.now < t:
                     self._await_device(t)
-                    continue          # re-check commits, re-peek
-                stepped = self.cluster.step(self.clock.now)
-            else:
-                if self._pace and not self._pace_until(t):
-                    continue          # ingress preempted: re-peek
-                stepped = self.cluster.step()
+                    continue          # re-check commits and ingress
+            stepped = self.cluster.step(self.clock.now if device else None)
             if stepped is None:
                 continue
             steps += 1

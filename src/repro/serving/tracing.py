@@ -234,9 +234,10 @@ class Tracer:
         self.steps: deque = deque(maxlen=self.STEPS_KEPT)
         #: (tracer clock, event time) at each dispatch under a WallClock
         self.lag: deque = deque(maxlen=self.LAG_KEPT)
-        #: (tracer clock, device end minus modelled end) per device-timed
-        #: commit
-        self.leads: deque = deque(maxlen=self.LAG_KEPT)
+        #: (wall time, device end minus modelled end) per device-timed
+        #: commit: the cluster's ``commit_model_lead``, which the serving
+        #: loop binds here
+        self.leads = ()
         self._next_sync = float("-inf")
 
     # ------------------------------------------------------------------
@@ -271,11 +272,6 @@ class Tracer:
             self._next_sync = wall + self.CLOCK_SYNC_EVERY
             self.clock_sync()
         return wall
-
-    def commit_lead(self, t: float, lead: float):
-        """Record one device-timed commit at ``t``: how far the device's
-        end ran past the cost model's (seconds, negative when early)."""
-        self.leads.append((self._stamp(t, {}), lead))
 
     def clock_sync(self):
         """Write a ``taichi.clock`` annotation into the profiler's trace
