@@ -30,7 +30,7 @@ from pathlib import Path
 from types import SimpleNamespace
 from typing import Callable, Optional
 
-from . import flops, trace as T, traffic as traffic_lib, weights
+from . import trace as T, traffic as traffic_lib, weights
 from .manifest import BENCH, Cell
 
 #: compiled programs are kept here, at a fixed path inside the checkout
@@ -83,31 +83,18 @@ def use_compile_cache() -> dict:
             "env_not_used": os.environ.get("JAX_COMPILATION_CACHE_DIR")}
 
 
-def model_config(conf: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig
-    m = weights.dims(conf)
-    return ModelConfig(
-        name=conf["name"], family="dense", num_layers=m["L"],
-        d_model=m["d"], num_heads=m["hq"], num_kv_heads=m["hkv"],
-        d_ff=m["ff"], vocab_size=m["V"], head_dim=m["dh"],
-        qkv_bias=bool(conf.get("attention_bias")),
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        dtype=conf.get("torch_dtype", "bfloat16"), source=conf["source"])
-
-
-def build_engine(conf: dict, slo, seed: int):
-    """``build_engine`` of the program, serving the benchmark's seeded
-    weights: the program's own initializer is bypassed while it builds,
-    and the tree handed over must match what that initializer makes."""
+def build_engine(arch, conf: dict, slo, seed: int):
+    """``build_engine`` of the program for ``arch``'s ``ModelConfig``,
+    serving the benchmark's seeded weights: the program's own initializer
+    is bypassed while it builds, and the tree handed over must match
+    what that initializer makes."""
     import jax
     from repro.configs.registry import register
     from repro.core.policies import Sliders
     from repro.launch import serve
     from repro.models import transformer as tf
-    cfg = register(model_config(conf))
-    params = weights.all_params(conf, seed)
+    cfg = register(arch.model_config(conf))
+    params = weights.all_params(arch, conf, seed)
     want = jax.tree.map(lambda a: (a.shape, a.dtype), tf.abstract_params(cfg))
     got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
     if want != got:
@@ -374,7 +361,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     jax.monitoring.register_event_listener(on_event)
 
     t_build = time.monotonic()
-    engine = build_engine(conf, slo, seed)
+    engine = build_engine(cell.arch, conf, slo, seed)
     stats = dev.memory_stats() or {}
     log(built_s=time.monotonic() - t_build, kernels=engine.kernels,
         hbm_blocks=engine.sc.hbm_blocks, kv_pool_bytes=engine.pool_bytes,
@@ -473,7 +460,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
               "attempted": e2e["attempted"], "failed": e2e["failed"]}
     if trace:
         ctx = SimpleNamespace(trace=trace_view, probe=probe, conf=conf,
-                              peaks=peaks, window=(w0, w1))
+                              arch=cell.arch, peaks=peaks, window=(w0, w1))
         metrics = {}
         for m in cell.per_layer:
             v = cell.reader(m["name"]).read(ctx)
@@ -557,14 +544,14 @@ def check(cell: Cell, seed: int, client: dict, served: dict, cap: int,
             n += len(s.out)
         t = time.monotonic()
         seqs = [(s.prompt, s.out) for s in sample]
-        logits = reference.served_logits(cell.conf, seed, seqs)
+        logits = reference.served_logits(cell.arch, cell.conf, seed, seqs)
         nums = reference.gap_numbers(
             [reference.gaps(lg, s.out) for lg, s in zip(logits, sample)])
         if control:
             # the control in the program's place: at each position the
             # token the int8 network puts first, judged as served
             program = nums
-            ctrl = reference.served_logits(cell.conf, seed, seqs,
+            ctrl = reference.served_logits(cell.arch, cell.conf, seed, seqs,
                                            precision="int8")
             nums = reference.gap_numbers(
                 [reference.gaps(lg, c.argmax(-1))

@@ -3,13 +3,11 @@
 matrix product at ``highest`` precision.  It imports nothing of the
 program and draws its weights again from the seed (``weights``).
 
-Layer equations (Qwen2 / Llama): ``h = x + Attn(RMSNorm(x))``,
-``x' = h + W_down(silu(W_gate n) * W_up n)`` with ``n = RMSNorm(h)``;
-RMSNorm scales by its gain after ``x / sqrt(mean(x^2) + eps)``; RoPE
-rotates the two halves of each head (``rotate_half``) at angle
-``pos * theta^(-2i/d)``; grouped-query attention with causal masking;
-q/k/v biases where the configuration has them; logits from the final
-RMSNorm through the output head.
+The layers are the architecture module's (``bench/arch/<arch>.py``,
+``block``), built from the pieces here: ``matmul``, ``rms_norm`` (which
+scales by its gain after ``x / sqrt(mean(x^2) + eps)``) and causal
+``attention``.  This file embeds the tokens, runs the layers and takes
+the logits from the final RMSNorm through the output head.
 
 It runs layer by layer over a set of whole sequences (prompt plus the
 served tokens, teacher-forced), so that one layer's weights and the
@@ -19,7 +17,9 @@ positions that chose each served token.
 ``precision="int8"`` is the control: the same network with every
 matrix product in int8 (weights per output channel, activations per
 token, symmetric, accumulated in int32) and attention in bfloat16 —
-the step below the bfloat16 the configuration states.
+the step below the bfloat16 the configuration states.  A ``block``
+that computes its products through ``matmul`` and ``attention`` follows
+the control without knowing of it.
 """
 from __future__ import annotations
 
@@ -47,9 +47,9 @@ def matmul(x, w, precision: str):
     w = w.astype(jnp.float32)
     if precision == "f32":
         return jnp.matmul(x, w, precision=HIGHEST)
-    xq, xs = _quant(x, -1)
-    wq, ws = _quant(w, 0)
-    acc = jnp.matmul(xq, wq, preferred_element_type=jnp.int32)
+    xi, xs = _quant(x, -1)
+    wi, ws = _quant(w, 0)
+    acc = jnp.matmul(xi, wi, preferred_element_type=jnp.int32)
     return acc.astype(jnp.float32) * xs * ws
 
 
@@ -59,78 +59,52 @@ def rms_norm(x, gain, eps):
         * (1.0 + gain.astype(jnp.float32))
 
 
-def rope(x, theta):
-    """x [S, H, D] at positions 0..S-1, rotate-half convention."""
-    S, _, D = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :D // 2], x[..., D // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def block(conf: dict, w: dict, x, precision: str):
-    """One decoder layer over one sequence x [S, d] (float32)."""
-    m = W.dims(conf)
-    S = x.shape[0]
-    eps, theta = conf["rms_norm_eps"], conf["rope_theta"]
-    mm = lambda a, b: matmul(a, b, precision)
-    n = rms_norm(x, w["ln1"], eps)
-    q, k, v = mm(n, w["wq"]), mm(n, w["wk"]), mm(n, w["wv"])
-    if "bq" in w:
-        q = q + w["bq"].astype(jnp.float32)
-        k = k + w["bk"].astype(jnp.float32)
-        v = v + w["bv"].astype(jnp.float32)
-    q = rope(q.reshape(S, m["hq"], m["dh"]), theta)
-    k = rope(k.reshape(S, m["hkv"], m["dh"]), theta)
-    v = v.reshape(S, m["hkv"], m["dh"])
-    g = m["hq"] // m["hkv"]
-    k = jnp.repeat(k, g, axis=1)
-    v = jnp.repeat(v, g, axis=1)
+def attention(q, k, v, scale, precision: str):
+    """Causal attention of one sequence: q, k [S, H, D], v [S, H, Dv]
+    (float32, as many key heads as query heads) -> [S, H, Dv].  Scores
+    and the weighted sum at ``highest`` (``f32``) or in bfloat16
+    (``int8``)."""
+    S = q.shape[0]
     if precision == "f32":
         s = jnp.einsum("shd,thd->hst", q, k, precision=HIGHEST)
     else:
         s = jnp.einsum("shd,thd->hst", q.astype(jnp.bfloat16),
                        k.astype(jnp.bfloat16),
                        preferred_element_type=jnp.float32)
-    s = s * m["dh"] ** -0.5
+    s = s * scale
     causal = jnp.tril(jnp.ones((S, S), bool))
     p = jax.nn.softmax(jnp.where(causal[None], s, -jnp.inf), axis=-1)
     if precision == "f32":
-        o = jnp.einsum("hst,thd->shd", p, v, precision=HIGHEST)
-    else:
-        o = jnp.einsum("hst,thd->shd", p.astype(jnp.bfloat16),
-                       v.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
-    h = x + mm(o.reshape(S, m["hq"] * m["dh"]), w["wo"])
-    n = rms_norm(h, w["ln2"], eps)
-    gate = mm(n, w["w_gate"])
-    return h + mm(jax.nn.silu(gate) * mm(n, w["w_up"]), w["w_down"])
+        return jnp.einsum("hst,thd->shd", p, v, precision=HIGHEST)
+    return jnp.einsum("hst,thd->shd", p.astype(jnp.bfloat16),
+                      v.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(conf_json: str, precision: str):
+def _programs(arch, conf_json: str, precision: str):
     conf = json.loads(conf_json)
 
-    @jax.jit
-    def layer_w(seed_lo, seed_hi, l):
-        return W.layer(conf, W.base_key(seed_lo, seed_hi), l)
+    @functools.partial(jax.jit, static_argnames=("like",))
+    def layer_w(seed_lo, seed_hi, l, like):
+        return W.layer(arch, conf, W.base_key(seed_lo, seed_hi), l, like)
 
     @jax.jit
     def embed_rows(seed_lo, seed_hi, tokens):
-        e = W.embed(conf, W.base_key(seed_lo, seed_hi))
+        e = W.embed(arch, conf, W.base_key(seed_lo, seed_hi))
         return jnp.take(e, tokens, axis=0).astype(jnp.float32)
 
-    @jax.jit
-    def run_block(w, x):
-        return block(conf, w, x, precision)
+    @functools.partial(jax.jit, static_argnames=("l",))
+    def run_block(w, x, l):
+        return arch.block(conf, l, w, x, precision)
 
     @functools.partial(jax.jit, static_argnames=("rows",))
     def head(seed_lo, seed_hi, x, start, rows):
         key = W.base_key(seed_lo, seed_hi)
         x = jax.lax.dynamic_slice_in_dim(x, start, rows)
-        n = rms_norm(x, W.final_norm(conf, key), conf["rms_norm_eps"])
-        return matmul(n, W.lm_head(conf, key), precision)
+        n = rms_norm(x, W.final_norm(arch, conf, key),
+                     conf["rms_norm_eps"])
+        return matmul(n, W.lm_head(arch, conf, key), precision)
 
     return layer_w, embed_rows, run_block, head
 
@@ -139,17 +113,20 @@ def pad_len(n: int, step: int = 256) -> int:
     return -(-n // step) * step
 
 
-def served_logits(conf: dict, seed: int, seqs, precision: str = "f32"):
-    """Logits that chose each served token.
+def served_logits(arch, conf: dict, seed: int, seqs,
+                  precision: str = "f32"):
+    """Logits that chose each served token, through ``arch``'s layers.
 
     ``seqs``: list of (prompt ids, served ids).  Returns, per sequence, a
     float32 array [len(served), V]: row j holds the logits at the
     position whose next token is served token j (the last prompt token
     for j = 0).  Sequences are padded at the end to one length, which
-    causal attention leaves without effect on the positions read."""
+    causal attention leaves without effect on the positions read.  Layers
+    stacked together run one compiled ``block``, given the stack's first
+    layer."""
     lo, hi = W.split_seed(seed)
     layer_w, embed_rows, run_block, head = _programs(
-        json.dumps(conf, sort_keys=True), precision)
+        arch, json.dumps(conf, sort_keys=True), precision)
     fed = [list(p) + list(s[:-1]) for p, s in seqs]
     rows = pad_len(max(len(s) for _, s in seqs), 128)
     # room past the longest sequence for a whole block of head rows
@@ -157,9 +134,9 @@ def served_logits(conf: dict, seed: int, seqs, precision: str = "f32"):
     xs = [embed_rows(lo, hi, jnp.asarray(f + [0] * (S - len(f)), jnp.int32))
           for f in fed]
     with jax.default_matmul_precision("highest"):
-        for l in range(W.dims(conf)["L"]):
-            w = layer_w(lo, hi, jnp.uint32(l))
-            xs = [run_block(w, x) for x in xs]
+        for l, like in enumerate(W.like_of(arch, conf)):
+            w = layer_w(lo, hi, jnp.uint32(l), like=like)
+            xs = [run_block(w, x, l=like) for x in xs]
             del w
         out = []
         for (p, s), x in zip(seqs, xs):

@@ -5,14 +5,17 @@ The executor jits its fused mixed prefill+decode step as
 ``_mixed_fused`` and its K-step decode horizon as ``_horizon_paged``;
 the device trace names their executions after those functions
 (``jit__mixed_fused(<hash>)``).  The paged kernels appear as custom-call
-ops named after their jitted wrappers (``_paged_decode.12``).
+ops named after their jitted wrappers (``_paged_decode.12``); an
+architecture module that runs other kernels names their prefixes in
+its own ``KERNELS``.
 """
 from __future__ import annotations
 
 from . import flops, trace as T
 
 PROGRAMS = {"mixed": "_mixed_fused", "horizon": "_horizon_paged"}
-#: the kernels' ops are named after the jitted wrappers that call them
+#: the kernels' ops are named after the jitted wrappers that call them;
+#: the default for an architecture module without ``KERNELS``
 KERNELS = {"paged_decode": "_paged_decode", "paged_prefill": "_paged_prefill"}
 
 
@@ -41,7 +44,7 @@ def matched(ctx):
 
 def kernel_time(ctx, start, end, kernel: str) -> float:
     """Device seconds of one kernel's ops inside one execution."""
-    pat = KERNELS[kernel]
+    pat = getattr(ctx.arch, "KERNELS", KERNELS)[kernel]
     return sum(e - s for s, e, n in ctx.trace.ops_between(start, end)
                if n.startswith(pat)) * 1e-9
 
@@ -57,7 +60,7 @@ def roofline_share(ctx, kernel: str):
             continue
         spent += t
         for call in info.calls:
-            fl = sum(flops.attn_flops(ctx.conf, a, b) for a, b in call)
-            by = sum(flops.attn_bytes(ctx.conf, a, b) for a, b in call)
+            fl = sum(ctx.arch.attn_flops(ctx.conf, a, b) for a, b in call)
+            by = sum(ctx.arch.attn_bytes(ctx.conf, a, b) for a, b in call)
             least += flops.roofline_seconds(fl, by, ctx.peaks)
     return 100.0 * least / spent if spent > 0 else None

@@ -5,7 +5,11 @@ Every leaf is a function of (seed, leaf id, layer) alone, so the plain
 reference can draw one layer again by itself after the program's state
 is freed (``layer``) and get the very values the program served: it
 takes nothing the program made.  ``all_params`` draws the whole tree in
-one jitted call, in the configuration's dtype.
+one jitted call, in the configuration's dtype.  Which leaves a layer
+has, which layers are stacked together and how the program nests them
+is the architecture module's to say (``bench/arch/<arch>.py``:
+``layer_shapes``, ``segments``, ``tree``); the embedding, the output
+head and the final norm are drawn here.
 
 Values are uniform and centred: weight matrices with standard deviation
 ``fan_in ** -0.5``, the embedding with deviation 1, biases with 0.1, and
@@ -22,18 +26,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# leaf ids: a leaf's values never depend on which other leaves exist
+# leaf ids: a leaf's values never depend on which other leaves exist; an
+# architecture module numbers its layers' leaves from 10
 EMBED, LM_HEAD, FINAL_NORM = 0, 1, 2
-LN1, WQ, WK, WV, WO, BQ, BK, BV, LN2, W_GATE, W_UP, W_DOWN = range(10, 22)
+#: deviation of a bias, and of an RMSNorm gain's offset from 1
 SMALL = 0.1
-
-
-def dims(conf: dict) -> dict:
-    d = conf["hidden_size"]
-    hq, hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    dh = conf.get("head_dim") or d // hq
-    return dict(d=d, hq=hq, hkv=hkv, dh=dh, ff=conf["intermediate_size"],
-                V=conf["vocab_size"], L=conf["num_hidden_layers"])
 
 
 def dtype_of(conf: dict):
@@ -66,76 +63,74 @@ def _leaf(key, leaf, layer, shape, scale, dtype):
     return _uniform(k, shape, scale, dtype)
 
 
-def layer_shapes(conf: dict) -> dict:
-    """(leaf id, shape, scale) of every per-layer leaf, by name."""
-    m = dims(conf)
-    d, q, kv, ff = m["d"], m["hq"] * m["dh"], m["hkv"] * m["dh"], m["ff"]
-    out = {"ln1": (LN1, (d,), SMALL), "ln2": (LN2, (d,), SMALL),
-           "wq": (WQ, (d, q), d ** -0.5), "wk": (WK, (d, kv), d ** -0.5),
-           "wv": (WV, (d, kv), d ** -0.5), "wo": (WO, (q, d), q ** -0.5),
-           "w_gate": (W_GATE, (d, ff), d ** -0.5),
-           "w_up": (W_UP, (d, ff), d ** -0.5),
-           "w_down": (W_DOWN, (ff, d), ff ** -0.5)}
-    if conf.get("attention_bias"):
-        out.update(bq=(BQ, (q,), SMALL), bk=(BK, (kv,), SMALL),
-                   bv=(BV, (kv,), SMALL))
-    return out
-
-
-def layer(conf: dict, key, l) -> dict:
-    """One layer's leaves, flat by name, in the configuration's dtype."""
+def layer(arch, conf: dict, key, l, like=None) -> dict:
+    """Layer ``l``'s leaves, flat by name, in the configuration's dtype.
+    Where ``l`` is traced (under ``vmap`` or ``jit``), ``like`` is a
+    layer (a whole number) stacked with it, whose leaf shapes are drawn."""
     dt = dtype_of(conf)
+    shapes = arch.layer_shapes(conf, l if like is None else like)
     return {name: _leaf(key, leaf, l, shape, scale, dt)
-            for name, (leaf, shape, scale) in layer_shapes(conf).items()}
+            for name, (leaf, shape, scale) in shapes.items()}
 
 
-def embed(conf: dict, key):
-    m = dims(conf)
+def like_of(arch, conf: dict) -> list:
+    """For each layer, the ``like`` that ``layer`` takes for it: the
+    first layer of the stack it is drawn in."""
+    heads = {}
+    for seg in arch.segments(conf):
+        for layers in seg:
+            heads.update((l, layers[0]) for l in layers)
+    return [heads[l] for l in range(len(heads))]
+
+
+def embed(arch, conf: dict, key):
+    m = arch.dims(conf)
     return _leaf(key, EMBED, 0, (m["V"], m["d"]), 1.0, dtype_of(conf))
 
 
-def lm_head(conf: dict, key):
+def lm_head(arch, conf: dict, key):
     """The output matrix [d, V] (the embedding's transpose when tied)."""
     if conf.get("tie_word_embeddings"):
-        return embed(conf, key).T
-    m = dims(conf)
+        return embed(arch, conf, key).T
+    m = arch.dims(conf)
     return _leaf(key, LM_HEAD, 0, (m["d"], m["V"]), m["d"] ** -0.5,
                  dtype_of(conf))
 
 
-def final_norm(conf: dict, key):
-    return _leaf(key, FINAL_NORM, 0, (dims(conf)["d"],), SMALL,
+def final_norm(arch, conf: dict, key):
+    return _leaf(key, FINAL_NORM, 0, (arch.dims(conf)["d"],), SMALL,
                  dtype_of(conf))
 
 
-def _program_tree(conf: dict, flat: dict) -> dict:
-    """The program's nesting of one (stacked) layer's leaves."""
-    attn = {k: flat[k] for k in ("wq", "wk", "wv", "wo", "bq", "bk", "bv")
-            if k in flat}
-    return {"ln1": flat["ln1"], "attn": attn, "ln2": flat["ln2"],
-            "mlp": {k: flat[k] for k in ("w_gate", "w_up", "w_down")}}
+def _stacked(arch, conf: dict, key, layers) -> dict:
+    """The program's tree of ``layers`` (alike), stacked on a leading
+    axis."""
+    flat = jax.vmap(lambda l: layer(arch, conf, key, l, like=layers[0]))(
+        jnp.asarray(layers, jnp.uint32))
+    return arch.tree(conf, layers[0], flat)
 
 
 @functools.lru_cache(maxsize=None)
-def _all_params_fn(conf_json: str):
+def _all_params_fn(arch, conf_json: str):
     import json
     conf = json.loads(conf_json)
-    L = dims(conf)["L"]
 
     @jax.jit
     def make(seed_lo, seed_hi):
         key = base_key(seed_lo, seed_hi)
-        stacked = jax.vmap(lambda l: layer(conf, key, l))(
-            jnp.arange(L, dtype=jnp.uint32))
-        return {"embed": embed(conf, key), "lm_head": lm_head(conf, key),
-                "final_norm": final_norm(conf, key),
-                "segments": ((_program_tree(conf, stacked),),)}
+        return {"embed": embed(arch, conf, key),
+                "lm_head": lm_head(arch, conf, key),
+                "final_norm": final_norm(arch, conf, key),
+                "segments": tuple(
+                    tuple(_stacked(arch, conf, key, layers)
+                          for layers in seg)
+                    for seg in arch.segments(conf))}
 
     return make
 
 
-def all_params(conf: dict, seed: int) -> dict:
+def all_params(arch, conf: dict, seed: int) -> dict:
     """The whole parameter tree, made on the device in one jitted call."""
     import json
-    return _all_params_fn(json.dumps(conf, sort_keys=True))(
+    return _all_params_fn(arch, json.dumps(conf, sort_keys=True))(
         *split_seed(seed))

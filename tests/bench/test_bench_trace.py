@@ -1,4 +1,5 @@
 """The trace reduction on small hand-made traces (nanoseconds)."""
+import json
 from types import SimpleNamespace
 
 import pytest
@@ -6,6 +7,10 @@ import pytest
 import _paths  # noqa: F401
 from bench.core import steps
 from bench.core import trace as T
+from bench.core.manifest import ROOT, load_arch
+
+MANIFEST_CELL = json.loads((ROOT / "BENCHMARK.json").read_text())[
+    "workloads"][0]["name"]
 
 
 def test_union_merges_overlaps_and_clips():
@@ -85,7 +90,8 @@ def test_roofline_share_sums_least_time_over_kernel_time():
     tv.ops_between = lambda lo, hi: [
         (100, 1100, "_paged_decode.7"),
         (1100, 1500, "copy_bitcast_fusion.4")]
-    ctx = SimpleNamespace(trace=tv, conf=conf, peaks=peaks)
+    ctx = SimpleNamespace(trace=tv, conf=conf, peaks=peaks,
+                          arch=load_arch(ROOT, {"arch": "qwen2"}))
     ctx._matched = [(0, 2000, _info("paged_decode", [[(9, 1), (3, 1)]]))]
     # keys 10 + 4; bytes: K,V of (10 + 4) keys and q,o of 2 queries,
     # 2 layers, bf16: memory-bound
@@ -93,3 +99,41 @@ def test_roofline_share_sums_least_time_over_kernel_time():
     want = 100 * (nbytes / 1e9) / 1000e-9
     assert abs(steps.roofline_share(ctx, "paged_decode") - want) < 1e-9
     assert steps.roofline_share(ctx, "paged_prefill") is None
+
+
+def test_roofline_share_takes_the_kernel_prefixes_of_the_module():
+    """An architecture module that names its own kernels' ops is read by
+    those names, with its own counts; one that names none by the
+    default's."""
+    arch = SimpleNamespace(KERNELS={"paged_decode": "_latent_decode"},
+                           attn_flops=lambda conf, a, b: 0,
+                           attn_bytes=lambda conf, a, b: 100 * b)
+    tv = SimpleNamespace(ops=[], window=(0, 10_000_000))
+    tv.ops_between = lambda lo, hi: [
+        (100, 600, "_latent_decode.3"), (600, 1100, "_paged_decode.7")]
+    ctx = SimpleNamespace(trace=tv, conf={}, arch=arch,
+                          peaks={"bf16_flops": 1e12, "hbm_bytes_per_s": 1e9})
+    ctx._matched = [(0, 2000, _info("paged_decode", [[(9, 1), (3, 1)]]))]
+    # 200 bytes at 1e9 bytes/s over the 500 ns of ``_latent_decode``
+    assert abs(steps.roofline_share(ctx, "paged_decode") - 40.0) < 1e-9
+    del arch.KERNELS
+    assert abs(steps.roofline_share(ctx, "paged_decode") - 40.0) < 1e-9
+    tv.ops_between = lambda lo, hi: [(100, 600, "_latent_decode.3")]
+    assert steps.roofline_share(ctx, "paged_decode") is None
+
+
+def test_step_mfu_counts_the_steps_through_the_module():
+    from bench.core import flops
+    from bench.core.manifest import Cell
+    arch = load_arch(ROOT, {"arch": "qwen2"})
+    conf = json.loads((ROOT / "bench" / "configs" / "qwen2.5-14b-pp4.json")
+                      .read_text())
+    rows = [(0, 256), (511, 1)]
+    info = SimpleNamespace(rows=rows)
+    tv = SimpleNamespace(window_s=lambda: 2.0)
+    ctx = SimpleNamespace(trace=tv, conf=conf, arch=arch,
+                          peaks={"bf16_flops": 1e15}, _matched=[
+                              (0, 10, info), (20, 30, info)])
+    reader = Cell(ROOT, MANIFEST_CELL).reader("step_mfu")
+    want = 100 * 2 * flops.step_model_flops(arch, conf, rows) / 2e15
+    assert abs(reader.read(ctx) - want) < 1e-9
